@@ -2,7 +2,10 @@
 
 Multi-device benches cannot set XLA_FLAGS in-process (the orchestrator
 must keep the default single device), so they follow the same subprocess
-pattern as tests/test_distributed.py.
+pattern as tests/test_distributed.py.  The children measure deterministic
+counts on host devices and always run on the CPU: a parent that has
+touched JAX holds the chip, and a child that opened it too would fail or
+hang.
 """
 from __future__ import annotations
 
@@ -26,6 +29,7 @@ def run_with_devices(body: str, ndev: int = 8, timeout: int = 1200) -> str:
     ) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = f"{REPO}/src:{REPO}"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, env=env, timeout=timeout,

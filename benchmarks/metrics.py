@@ -12,10 +12,11 @@ from __future__ import annotations
 import numpy as np
 
 _RC = np.array([3, 2, 1, 0, 4], dtype=np.uint8)
+_LETTERS = np.frombuffer(b"ACGTN", dtype=np.uint8)
 
 
 def _s(seq):
-    return "".join("ACGTN"[int(b)] for b in np.asarray(seq))
+    return _LETTERS[np.asarray(seq, dtype=np.uint8)].tobytes().decode()
 
 
 def _rc(seq):
@@ -72,14 +73,18 @@ def is_misassembled(hits, max_gap=100) -> bool:
     return False
 
 
-def genome_fraction(pieces, genome, w=30) -> float:
+def _windows(pieces, w=30) -> set:
+    """Every w-mer of the pieces, on both strands."""
     windows = set()
     for c in pieces:
-        s = _s(c)
-        sr = _s(_rc(c))
-        for src in (s, sr):
-            for i in range(len(src) - w + 1):
-                windows.add(src[i : i + w])
+        for src in (_s(c), _s(_rc(c))):
+            windows.update(src[i : i + w] for i in range(len(src) - w + 1))
+    return windows
+
+
+def genome_fraction(pieces, genome, w=30, windows=None) -> float:
+    if windows is None:
+        windows = _windows(pieces, w)
     g = _s(genome)
     n = len(g) - w + 1
     if n <= 0:
@@ -115,7 +120,9 @@ def evaluate(pieces, genomes, w=30, length_thresholds=(100, 250, 500)):
         if is_misassembled(hits):
             mis += 1
     report["misassemblies"] = mis
-    fracs = [genome_fraction(pieces, g, w) for g in genomes]
+    windows = _windows(pieces, w)
+    fracs = [genome_fraction(pieces, g, w, windows) for g in genomes]
+    report["genome_fractions"] = [float(f) for f in fracs]
     report["genome_fraction"] = float(np.mean(fracs))
     report["genome_fraction_min"] = float(np.min(fracs))
     return report

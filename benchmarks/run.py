@@ -25,6 +25,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     only = set(filter(None, args.only.split(","))) if args.only else None
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     run_started = time.time()
     benches = {}
     from . import bench_kernels, bench_quality, bench_localization, \

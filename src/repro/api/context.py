@@ -27,6 +27,17 @@ import jax.numpy as jnp
 from repro.core import alignment, kmer_analysis, local_assembly, scaffolding
 
 
+def _merge_contigs(tab, prev, k: int, plan):
+    """Fold the previous round's contig k-mers into a count table as
+    pseudo-counted evidence (§II-H)."""
+    contigs, alive = prev
+    return kmer_analysis.merge_contig_kmers(
+        tab, contigs.bases, jnp.where(alive, contigs.lengths, 0), k=k,
+        capacity=plan.kmer_capacity, weight=plan.contig_pseudo_weight,
+        backend=plan.kernel_backend,
+    )
+
+
 class ExecutionContext:
     """Stage protocol shared by Local and Mesh execution."""
 
@@ -135,19 +146,12 @@ class Local(ExecutionContext):
         tab = kmer_analysis.count_occurrences(
             hi, lo, left, right, valid, capacity=plan.kmer_capacity
         )
-        if prev is not None:
-            if not isinstance(prev, dict):
-                from .assembler import extract_contig_kmers
-
-                contigs, alive = prev
-                prev = extract_contig_kmers(
-                    contigs, alive, k=k, capacity=plan.kmer_capacity,
-                    weight=plan.contig_pseudo_weight,
-                    backend=plan.kernel_backend,
-                )
+        if isinstance(prev, dict):
             tab = kmer_analysis.merge_counts(
                 tab, prev, capacity=plan.kmer_capacity
             )
+        elif prev is not None:
+            tab = _merge_contigs(tab, prev, k, plan)
         self._note_overflow("kmer_table", tab["overflow"])
         kset = kmer_analysis.finalize(
             tab, min_count=plan.min_count, policy=plan.policy
@@ -199,17 +203,7 @@ class Local(ExecutionContext):
             backend=plan.kernel_backend,
         )
         if prev is not None:
-            from .assembler import extract_contig_kmers
-
-            contigs, alive = prev
-            ptab = extract_contig_kmers(
-                contigs, alive, k=k, capacity=plan.kmer_capacity,
-                weight=plan.contig_pseudo_weight,
-                backend=plan.kernel_backend,
-            )
-            run = kmer_analysis.merge_counts(
-                run, ptab, capacity=plan.kmer_capacity
-            )
+            run = _merge_contigs(run, prev, k, plan)
             sstats.table_overflow += int(run["overflow"])
         self._note_overflow("kmer_table", sstats.table_overflow)
         kset = kmer_analysis.finalize(
@@ -398,17 +392,7 @@ class Mesh(ExecutionContext):
         )
         sstats.table_overflow += int(merged["overflow"])
         if prev is not None:
-            from .assembler import extract_contig_kmers
-
-            contigs, alive = prev
-            ptab = extract_contig_kmers(
-                contigs, alive, k=k, capacity=plan.kmer_capacity,
-                weight=plan.contig_pseudo_weight,
-                backend=plan.kernel_backend,
-            )
-            merged = kmer_analysis.merge_counts(
-                merged, ptab, capacity=plan.kmer_capacity
-            )
+            merged = _merge_contigs(merged, prev, k, plan)
             sstats.table_overflow += int(merged["overflow"])
         self._note_overflow("kmer_table", sstats.table_overflow)
         self._note_overflow("kmer_route", sstats.route_overflow)

@@ -171,11 +171,11 @@ class AssemblyPlan:
     # without touching call sites.
     gapped_align: bool = False
     # --- kernel backend (DESIGN.md §8) ---
-    # "pallas" | "ref" | None (None = the hardware-aware kernels.ops
-    # default — pallas on TPU, ref elsewhere — overridable process-wide
-    # via the REPRO_KERNELS env var).  Selects which implementation serves
-    # the fused k-mer extraction hot path in every stage this plan
-    # drives; both backends are bit-identical.
+    # "pallas" | "ref" | None (None = the kernels.ops default, "ref" on
+    # every platform — overridable process-wide via the REPRO_KERNELS env
+    # var).  Selects which implementation serves the fused hot paths in
+    # every stage this plan drives; both backends are bit-identical, but
+    # the Pallas kernels do not lower for TPU v5e yet.
     kernel_backend: Optional[str] = None
     # --- local assembly ---
     walk_ladder_step: int = 4

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 
-from . import dht, kmer
+from . import dht, kmer, kmer_analysis
 from .types import ContigSet, ReadSet
 
 NONE = jnp.int32(-1)
@@ -68,46 +68,52 @@ def build_seed_index(
     DESIGN.md §8): the canonical codes and the strand flip come straight
     out of the extraction lanes.
     """
-    C, Lmax = contigs.bases.shape
-    lengths = jnp.where(alive, contigs.lengths, 0)
-    W = Lmax - seed_len + 1
-    lanes = ops.kmer_extract(contigs.bases, lengths, k=seed_len,
-                             backend=backend)
-    chi, clo = lanes.hi[:, :W], lanes.lo[:, :W]
-    flip, valid = lanes.flip[:, :W], lanes.valid[:, :W]
-    cids = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[:, None], (C, W))
-    poss = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :], (C, W))
-    flat = lambda x: x.reshape((-1,))
-    fhi, flo, fvalid = flat(chi), flat(clo), flat(valid)
-    fcid, fpos, fflip = flat(cids), flat(poss), flat(flip)
-    # sort by key to detect multi-occurrence seeds
-    shi = jnp.where(fvalid, fhi, jnp.uint32(0xFFFFFFFF))
-    slo = jnp.where(fvalid, flo, jnp.uint32(0xFFFFFFFF))
-    idx = jnp.arange(fhi.shape[0], dtype=jnp.int32)
-    shi_s, slo_s, perm = jax.lax.sort((shi, slo, idx), num_keys=2)
-    first = jnp.concatenate(
-        [jnp.ones((1,), bool), (shi_s[1:] != shi_s[:-1]) | (slo_s[1:] != slo_s[:-1])]
+    table, contig_a, pos_a, flip_a, multi_a = _seed_index(
+        contigs, alive, seed_len=seed_len, capacity=capacity,
+        backend=ops.resolve_backend(backend),
     )
-    dup = ~first
-    valid_s = fvalid[perm]
-    # a key is multi iff any member beyond the first is valid
-    # (propagate per-key: segment-max of dup over the group)
-    seg = jnp.cumsum(first.astype(jnp.int32)) - 1
-    nseg = fhi.shape[0]
-    group_multi = jnp.zeros((nseg,), bool).at[seg].max(dup & valid_s)
-    is_rep = first & valid_s
-    table, slots = dht.build(shi_s, slo_s, is_rep, capacity=capacity,
-                             backend=backend)
-    cap = table.capacity
-    sel = jnp.where(is_rep, slots, cap)
-    contig_a = jnp.full((cap,), NONE).at[sel].set(fcid[perm], mode="drop")
-    pos_a = jnp.full((cap,), NONE).at[sel].set(fpos[perm], mode="drop")
-    flip_a = jnp.zeros((cap,), bool).at[sel].set(fflip[perm], mode="drop")
-    multi_a = jnp.zeros((cap,), bool).at[sel].set(group_multi[seg], mode="drop")
     return SeedIndex(
         table=table, contig=contig_a, pos=pos_a, flip=flip_a, multi=multi_a,
         seed_len=seed_len,
     )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("seed_len", "capacity", "backend"))
+def _seed_index(contigs: ContigSet, alive, *, seed_len: int, capacity: int,
+                backend: str):
+    """The arrays of `build_seed_index`, as one compiled program (the
+    backend is resolved before the call, so an override is never hidden
+    by a cached program)."""
+    C, W = contigs.bases.shape
+    lengths = jnp.where(alive, contigs.lengths, 0)
+    # full-width lanes (the last seed_len - 1 windows invalid): one shape,
+    # and one compiled build, for every seed length
+    lanes = ops.kmer_extract(contigs.bases, lengths, k=seed_len,
+                             backend=backend)
+    cids = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32)[:, None], (C, W))
+    poss = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :], (C, W))
+    flat = lambda x: x.reshape((-1,))
+    # the table holds at most `capacity` seeds: build over the valid ones
+    # only, not every pad position of the contig rows
+    (fhi, flo, fcid, fpos, fflip), fvalid, _ = kmer_analysis.compact_lanes(
+        flat(lanes.valid), capacity,
+        flat(lanes.hi), flat(lanes.lo), flat(cids), flat(poss),
+        flat(lanes.flip),
+    )
+    # every occurrence goes in; the table dedupes repeats onto one slot
+    table, slots = dht.build(fhi, flo, fvalid, capacity=capacity,
+                             backend=backend)
+    cap = table.capacity
+    sel = jnp.where(fvalid & (slots >= 0), slots, cap)
+    # a seed at more than one contig position is multi and never votes
+    # (seed_probe), so which occurrence the side arrays keep for it does
+    # not matter; a unique seed's single occurrence is the only writer
+    occ = jnp.zeros((cap,), jnp.int32).at[sel].add(1, mode="drop")
+    contig_a = jnp.full((cap,), NONE).at[sel].set(fcid, mode="drop")
+    pos_a = jnp.full((cap,), NONE).at[sel].set(fpos, mode="drop")
+    flip_a = jnp.zeros((cap,), bool).at[sel].set(fflip, mode="drop")
+    return table, contig_a, pos_a, flip_a, occ > 1
 
 
 def _seed_positions(read_len_max: int, seed_len: int, stride: int):
@@ -119,8 +125,15 @@ def _seed_positions(read_len_max: int, seed_len: int, stride: int):
 
 
 def _verify(reads: ReadSet, contigs: ContigSet, cid, cstart, orient):
-    """Hamming-extension verification of one candidate per read."""
+    """Hamming-extension verification of one candidate per read.
+
+    Read columns are padded to the TPU's lane tiling (`kmer.LANE_TILE`):
+    a contig gather indexed by an [R, 150] array compiles for minutes on
+    a v5e, by an [R, 256] one in seconds.  Pad columns lie past every
+    read's length and never count."""
     R, L = reads.bases.shape
+    pad = (-L) % kmer.LANE_TILE
+    L += pad
     i = jnp.arange(L, dtype=jnp.int32)[None, :]
     fwd_cpos = cstart[:, None] + i
     rc_cpos = cstart[:, None] + (reads.lengths[:, None] - 1 - i)
@@ -128,7 +141,7 @@ def _verify(reads: ReadSet, contigs: ContigSet, cid, cstart, orient):
     clen = jnp.where(cid >= 0, contigs.lengths[jnp.clip(cid, 0)], 0)
     inside = (cpos >= 0) & (cpos < clen[:, None]) & (i < reads.lengths[:, None])
     cbase = contigs.bases[jnp.clip(cid, 0)[:, None], jnp.clip(cpos, 0)]
-    rbase = reads.bases[:, : L]
+    rbase = jnp.pad(reads.bases, ((0, 0), (0, pad)), constant_values=4)
     rbase_cmp = jnp.where(orient[:, None] == 0, rbase, kmer.complement_base(rbase))
     match = inside & (cbase == rbase_cmp) & (rbase < 4)
     return match.sum(axis=-1), inside.sum(axis=-1)

@@ -35,6 +35,7 @@ class KmerIndex(NamedTuple):
     slot_to_row: jnp.ndarray  # [table_cap] int32
 
 
+@functools.partial(jax.jit, static_argnames=("table_capacity",))
 def build_index(kset: KmerSet, table_capacity: int | None = None) -> KmerIndex:
     cap = table_capacity or 2 * kset.capacity
     table, slots = dht.build(kset.hi, kset.lo, kset.used, capacity=cap)
@@ -136,11 +137,12 @@ def traverse(
     ohi, olo = _oriented_code(kset, k=k)
     last = kmer.last_base(ohi, olo, k=k)  # oriented last base, [2N]
     bases = jnp.full((contig_cap, max_len), 4, jnp.uint8)
-    # head writes its k bases
-    head_nodes_sel = jnp.where(is_head, cid_all, contig_cap)
-    head_kmer = kmer.decode(ohi, olo, k=k)  # [2N, k]
-    col = jnp.arange(k, dtype=jnp.int32)[None, :]
-    bases = bases.at[head_nodes_sel[:, None], col].set(head_kmer, mode="drop")
+    # head writes its k bases: contig c's head is the c-th head node, so
+    # decode just those [contig_cap] k-mers into the first k columns
+    heads = jnp.nonzero(is_head, size=contig_cap, fill_value=0)[0]
+    head_kmer = kmer.decode(ohi[heads], olo[heads], k=k)  # [contig_cap, k]
+    has_head = jnp.arange(contig_cap) < n_contigs
+    bases = bases.at[:, :k].set(jnp.where(has_head[:, None], head_kmer, 4))
     # non-head nodes write one base at k-1+dist
     tail_sel = keep & (chains.dist > 0) & (node_cid >= 0)
     row_idx = jnp.where(tail_sel, node_cid, contig_cap)
@@ -171,6 +173,7 @@ def traverse(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("k", "contig_cap"))
 def end_neighbor_forks(
     kset: KmerSet, index: KmerIndex, trav: Traversal, *, k: int, contig_cap: int
 ):

@@ -4,8 +4,10 @@ Each gap between adjacent scaffold members is attacked with the localized
 mer-walk from local_assembly (HipMer's "spanning k-mer walk" closure
 method): walk rightward from the left contig's inward-facing end, using
 reads localized to either flanking contig, and check whether the walk
-reaches the right contig's leading k-mer.  Unclosed gaps render as N runs
-sized by the link's gap estimate.
+reaches the right contig's leading k-mer.  Flanks that already overlap
+(a negative gap estimate) are joined on the overlap, which may differ in a
+read error or two; the other unclosed gaps render as N runs sized by the
+link's gap estimate.
 
 Load-balance adaptation: HipMer round-robins gaps across processors
 because closure costs vary wildly; the vectorized lockstep walk makes every
@@ -14,8 +16,11 @@ round-robin (DESIGN.md §2).
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
@@ -25,25 +30,72 @@ from .types import ContigSet, ReadSet
 from .scaffolding import Scaffolds
 
 NONE = jnp.int32(-1)
+# flank overlaps joined without a walk: up to k - 1 plus two local assembly
+# extensions of max_ext = 64 bases long, differing in at most a couple of
+# read errors (the right flank's bases are rendered there)
+MAX_OVERLAP = 256
+MAX_OVERLAP_MISMATCHES = 2
+# scaffold rows rendered per step (bounds the render's temporaries)
+RENDER_ROWS = 256
 
 
 class ScaffoldSeqs(NamedTuple):
     bases: jnp.ndarray    # [S, Lmax] uint8 (4 = pad / N)
     lengths: jnp.ndarray  # [S] int32
     closed: jnp.ndarray   # [S, M] bool gap after member j was walk-closed
+    #                       or its flanks overlap (no N run)
     n_scaffolds: jnp.ndarray
 
 
-def _member_bases(contigs: ContigSet, cid, orient, Lmax: int):
-    """Oriented bases of one scaffold member, padded to Lmax."""
-    bases = contigs.bases[jnp.clip(cid, 0)]
-    length = jnp.where(cid >= 0, contigs.lengths[jnp.clip(cid, 0)], 0)
-    i = jnp.arange(Lmax, dtype=jnp.int32)[None, :]
-    # rc: base j = complement(base[len-1-j])
-    rc_idx = jnp.clip(length[:, None] - 1 - i, 0, Lmax - 1)
-    rc = kmer.complement_base(jnp.take_along_axis(bases, rc_idx, axis=1))
-    out = jnp.where(orient[:, None] == 0, bases, rc)
-    return jnp.where(i < length[:, None], out, 4).astype(jnp.uint8), length
+def _member_window(contigs: ContigSet, cid, orient, start, width: int):
+    """Oriented bases [start, start + width) of scaffold members (4 outside
+    the member), and the members' lengths.  One 2-D gather of just the
+    window, never a copy of whole contig rows."""
+    c = jnp.clip(cid, 0)
+    length = jnp.where(cid >= 0, contigs.lengths[c], 0)
+    q = start[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
+    # rc: base q = complement(base[len-1-q])
+    src = jnp.where(orient[:, None] == 0, q, length[:, None] - 1 - q)
+    b = contigs.bases[c[:, None], jnp.clip(src, 0, contigs.max_len - 1)]
+    b = jnp.where(orient[:, None] == 0, b, kmer.complement_base(b))
+    inside = (q >= 0) & (q < length[:, None])
+    return jnp.where(inside, b, 4).astype(jnp.uint8), length
+
+
+def _overlaps(contigs: ContigSet, lc, lo_, rc_, ro, *, min_overlap: int):
+    """Length of the suffix/prefix overlap of each gap's flanks.
+
+    Adjacent contigs overlap by up to k - 1 bases plus what local assembly
+    walked onto both ends, and the link's gap estimate is then negative.
+    A contig end can carry a read error, so an overlap may differ in up to
+    `MAX_OVERLAP_MISMATCHES` bases.  Seed filter: every overlap length o
+    up to `MAX_OVERLAP` whose first or last `min_overlap` bases match
+    exactly; the longest candidate must then match over its whole length
+    but for those mismatches.  Returns 0 where no such overlap exists.
+    """
+    W, m = MAX_OVERLAP, min_overlap
+    llen = jnp.where(lc >= 0, contigs.lengths[jnp.clip(lc, 0)], 0)
+    tail, _ = _member_window(contigs, lc, lo_, llen - W, W)
+    head, rlen = _member_window(contigs, rc_, ro, jnp.zeros_like(rc_), W)
+    # candidate p: the overlap starts at tail[p], o = W - p bases long
+    n_start = W - m + 1
+    seed = jnp.ones((lc.shape[0], n_start), bool)   # head[:m] at tail[p]
+    back = jnp.ones((lc.shape[0], n_start), bool)   # tail[-m:] at head[o-m]
+    for i in range(m):
+        seed &= tail[:, i : i + n_start] == head[:, i : i + 1]
+        back &= head[:, i : i + n_start][:, ::-1] == tail[:, W - m + i :
+                                                           W - m + i + 1]
+    seed |= back
+    p = jnp.argmax(seed, axis=1).astype(jnp.int32)   # longest candidate
+    o = W - p
+    i = jnp.arange(W, dtype=jnp.int32)[None, :]
+    tail_at = jnp.take_along_axis(
+        tail, jnp.clip(p[:, None] + i, 0, W - 1), axis=1
+    )
+    mismatches = ((i < o[:, None]) & (tail_at != head)).sum(axis=1)
+    ok = (seed.any(axis=1) & (mismatches <= MAX_OVERLAP_MISMATCHES)
+          & (o <= llen) & (o <= rlen))
+    return jnp.where(ok, o, 0)
 
 
 def _gap_walks(
@@ -132,10 +184,26 @@ def close_and_render_with_tables(
 ) -> ScaffoldSeqs:
     """Gap closure from prebuilt walk tables (streaming ingest accumulates
     them batch by batch, DESIGN.md §7; the in-memory wrapper above builds
-    them from the whole read set in one shot)."""
+    them from the whole read set in one shot).
+
+    A gap the walk does not close but whose flanks overlap (by `seed_len`
+    to `MAX_OVERLAP` bases; see `_overlaps`) renders the overlap once; any
+    other open gap renders as an N run sized by the gap estimate."""
+    return _close_and_render(
+        scaffs, contigs, wt, seed_len=seed_len, mer_sizes=tuple(mer_sizes),
+        max_walk=max_walk, max_scaffold_len=max_scaffold_len,
+        max_n_run=max_n_run, backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "seed_len", "mer_sizes", "max_walk", "max_scaffold_len", "max_n_run",
+    "backend"))
+def _close_and_render(scaffs, contigs, wt, *, seed_len: int, mer_sizes: tuple,
+                      max_walk: int, max_scaffold_len: int, max_n_run: int,
+                      backend: str) -> ScaffoldSeqs:
     S, M = scaffs.contig.shape
     C = contigs.capacity
-    Lc = contigs.max_len
     tag_bits = min(16, 62 - 2 * max(mer_sizes))
     # per (scaffold, j) gap: left member j, right member j+1
     left_c = scaffs.contig
@@ -158,8 +226,8 @@ def close_and_render_with_tables(
     tail_hi = jnp.where(lo_ == 0, bhi[C:][lsel], bhi[:C][lsel])
     tail_lo = jnp.where(lo_ == 0, blo[C:][lsel], blo[:C][lsel])
     # target: right member's leading seed k-mer in scaffold orientation
-    rbases, _ = _member_bases(contigs, rc_, ro, Lc)
-    t_hi, t_lo = kmer.pack_window(rbases[:, :seed_len], k=seed_len)
+    rhead, _ = _member_window(contigs, rc_, ro, jnp.zeros_like(rc_), seed_len)
+    t_hi, t_lo = kmer.pack_window(rhead, k=seed_len)
     walk, hit, hit_pos = _gap_walks(
         wt,
         mer_sizes=tuple(mer_sizes),
@@ -176,52 +244,87 @@ def close_and_render_with_tables(
     )
     # closure bases: the walked bases minus the trailing seed overlap
     fill_len = jnp.where(hit, jnp.clip(hit_pos - seed_len, 0), NONE)  # -1: open
+    overlap = _overlaps(contigs, lc, lo_, rc_, ro, min_overlap=seed_len)
+    overlap = jnp.where(g_active & (fill_len < 0), overlap, 0).reshape(S, M)
     # ---- render ----
     est_gap = jnp.clip(scaffs.gap, 1.0, float(max_n_run)).astype(jnp.int32)
     gap_len = jnp.where(
         fill_len.reshape(S, M) >= 0, fill_len.reshape(S, M),
-        jnp.where(gap_active, est_gap, 0),
+        jnp.where(overlap > 0, -overlap, jnp.where(gap_active, est_gap, 0)),
     )
-    # member lengths + offsets
-    lens = jnp.where(
-        scaffs.contig >= 0, contigs.lengths[jnp.clip(scaffs.contig, 0)], 0
+    out, lengths = _render(
+        scaffs.contig, scaffs.orient, scaffs.n_members, gap_len,
+        jnp.clip(fill_len, 0).reshape(S, M), walk.ext_bases, contigs,
+        max_scaffold_len=max_scaffold_len,
     )
+    return ScaffoldSeqs(
+        bases=out,
+        lengths=lengths,
+        closed=(fill_len.reshape(S, M) >= 0) | (overlap > 0),
+        n_scaffolds=scaffs.n_scaffolds,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("max_scaffold_len",))
+def _render(member, orient, n_members, gap_len, walk_len, walk_bases,
+            contigs: ContigSet, *, max_scaffold_len: int):
+    """Scaffold rows: each member's oriented bases at its offset, then the
+    walked closure bases of the gap after it, else N.
+
+    Every output position takes its bases from the last member that starts
+    at or before it: members fill a prefix of each row and their offsets
+    never decrease (an overlap is at most the right member's length), so
+    where flanks overlap the right flank's bases win.  One gather per
+    position; nothing is scattered.  Rows go RENDER_ROWS at a time, so the
+    per-position temporaries stay a small multiple of that block."""
+    S, M = member.shape
+    Lc = contigs.max_len
+    valid = member >= 0
+    c = jnp.clip(member, 0)
+    lens = jnp.where(valid, contigs.lengths[c], 0)
     step = lens + gap_len
     offsets = jnp.concatenate(
         [jnp.zeros((S, 1), jnp.int32), jnp.cumsum(step, axis=1)[:, :-1]], axis=1
     )
-    total = jnp.max(jnp.where(scaffs.contig >= 0, offsets + lens, 0), axis=1)
-    out = jnp.full((S, max_scaffold_len), 4, jnp.uint8)
-    pos_in_contig = jnp.arange(Lc, dtype=jnp.int32)
-    for j in range(M):
-        mb, ml = _member_bases(contigs, scaffs.contig[:, j], scaffs.orient[:, j], Lc)
-        rowpos = offsets[:, j : j + 1] + pos_in_contig[None, :]
-        okm = (pos_in_contig[None, :] < ml[:, None]) & (
-            scaffs.contig[:, j : j + 1] >= 0
-        ) & (rowpos < max_scaffold_len)
-        rows = jnp.broadcast_to(jnp.arange(S)[:, None], (S, Lc))
-        out = out.at[
-            jnp.where(okm, rows, S), jnp.clip(rowpos, 0, max_scaffold_len - 1)
-        ].set(mb, mode="drop")
-        # walked closure bases after member j (flat gap index = s*M + j)
-        flat_idx = jnp.arange(S) * M + j
-        wbases = walk.ext_bases[flat_idx]  # [S, max_walk]
-        wlen = jnp.clip(fill_len[flat_idx], 0)
-        closed_j = fill_len[flat_idx] >= 0
-        wpos = jnp.arange(walk.ext_bases.shape[1], dtype=jnp.int32)
-        growpos = offsets[:, j : j + 1] + ml[:, None] + wpos[None, :]
-        okw = (wpos[None, :] < wlen[:, None]) & closed_j[:, None] & (
-            growpos < max_scaffold_len
-        )
-        rows2 = jnp.broadcast_to(jnp.arange(S)[:, None], (S, walk.ext_bases.shape[1]))
-        out = out.at[
-            jnp.where(okw, rows2, S), jnp.clip(growpos, 0, max_scaffold_len - 1)
-        ].set(wbases, mode="drop")
-    lengths = jnp.minimum(total, max_scaffold_len)
-    lengths = jnp.where(scaffs.n_members > 0, lengths, 0)
-    return ScaffoldSeqs(
-        bases=out,
-        lengths=lengths,
-        closed=(fill_len.reshape(S, M) >= 0),
-        n_scaffolds=scaffs.n_scaffolds,
+    total = jnp.max(jnp.where(valid, offsets + lens, 0), axis=1)
+    pos = jnp.arange(max_scaffold_len, dtype=jnp.int32)[None, :]
+    W = walk_bases.shape[1]
+
+    def rows(blk):
+        valid, offsets, lens, c, orient, walk_len, walk_bases = blk
+        B = valid.shape[0]
+        # the covering member's fields, selected member by member (M is
+        # small)
+        j = jnp.full((B, max_scaffold_len), -1, jnp.int32)
+        off = ml = cid = ori = wl = jnp.zeros((B, max_scaffold_len), jnp.int32)
+        for m in range(M):
+            here = valid[:, m : m + 1] & (offsets[:, m : m + 1] <= pos)
+            j = jnp.where(here, m, j)
+            off = jnp.where(here, offsets[:, m : m + 1], off)
+            ml = jnp.where(here, lens[:, m : m + 1], ml)
+            cid = jnp.where(here, c[:, m : m + 1], cid)
+            ori = jnp.where(here, orient[:, m : m + 1].astype(jnp.int32), ori)
+            wl = jnp.where(here, walk_len[:, m : m + 1], wl)
+        q = pos - off
+        # rc: base q = complement(base[len-1-q])
+        src = jnp.where(ori == 0, q, ml - 1 - q)
+        b = contigs.bases[cid, jnp.clip(src, 0, Lc - 1)]
+        b = jnp.where(ori == 0, b, kmer.complement_base(b))
+        # walked closure bases of the gap after member j
+        wq = q - ml
+        wb = walk_bases[jnp.arange(B)[:, None], jnp.clip(j, 0),
+                        jnp.clip(wq, 0, W - 1)]
+        out = jnp.where((j >= 0) & (q < ml), b,
+                        jnp.where((j >= 0) & (wq >= 0) & (wq < wl), wb, 4))
+        return out.astype(jnp.uint8)
+
+    B = math.gcd(S, RENDER_ROWS)
+    blocks = tuple(
+        x.reshape((S // B, B) + x.shape[1:])
+        for x in (valid, offsets, lens, c, orient, walk_len,
+                  walk_bases.reshape(S, M, W))
     )
+    out = jax.lax.map(rows, blocks).reshape(S, max_scaffold_len)
+    lengths = jnp.minimum(total, max_scaffold_len)
+    lengths = jnp.where(n_members > 0, lengths, 0)
+    return out, lengths

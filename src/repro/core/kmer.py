@@ -220,6 +220,23 @@ def embed_tag(hi, lo, tag, *, k: int, tag_bits: int):
     return hi | spill, new_lo
 
 
+#: XLA:TPU tiles an array's minor dimension by 128.  Flattening [R, L]
+#: rows with L off that tiling is a relayout whose TPU compile takes
+#: minutes at read-set scale (a 50,000 x 150 uint32 array: ~2 min on a
+#: described v5e), and seconds once the rows are padded to the tiling.
+LANE_TILE = 128
+
+
+def flat_lanes(x, fill):
+    """[R, L] -> [R * Lp] row-major, with Lp = L rounded up to LANE_TILE
+    and the padding columns set to `fill` (callers pass a value that reads
+    as invalid)."""
+    pad = (-x.shape[1]) % LANE_TILE
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)), constant_values=fill)
+    return x.reshape((-1,))
+
+
 def complement_base(b):
     """3 - b for real bases; INVALID stays invalid."""
     return jnp.where(b < 4, (3 - b).astype(b.dtype), b)

@@ -24,8 +24,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ops
 
-from . import bloom
-from .types import EMPTY_HI, EXT_F, EXT_X, KmerSet, ReadSet
+from . import bloom, kmer
+from .types import EMPTY_HI, EXT_F, EXT_X, INVALID_BASE, KmerSet, ReadSet
 
 
 class ExtensionPolicy(NamedTuple):
@@ -51,13 +51,41 @@ def occurrences(reads: ReadSet, *, k: int, backend=None):
     extraction path; `backend` selects pallas/ref per the plan or the
     REPRO_KERNELS override.
 
-    Returns (hi, lo, left, right, valid), each [R * (L-k+1)].
+    Returns (hi, lo, left, right, valid), each [R * Lp]: the lanes keep the
+    read width, the last k - 1 windows of a row invalid, so every k of the
+    ladder hands the count fold one shape and it compiles once.  Rows are
+    padded to Lp, the width rounded up to the TPU's lane tiling
+    (`kmer.flat_lanes`), with invalid lanes.
     """
-    lanes = ops.kmer_extract(reads.bases, reads.lengths, k=k, backend=backend)
-    W = reads.bases.shape[1] - k + 1
-    flat = lambda x: x[:, :W].reshape((-1,))
-    return (flat(lanes.hi), flat(lanes.lo), flat(lanes.left),
-            flat(lanes.right), flat(lanes.valid))
+    return _occurrences(reads.bases, reads.lengths, k=k,
+                        backend=ops.resolve_backend(backend))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "backend"))
+def _occurrences(bases, lengths, *, k: int, backend: str):
+    lanes = ops.kmer_extract(bases, lengths, k=k, backend=backend)
+    flat = kmer.flat_lanes
+    return (flat(lanes.hi, 0), flat(lanes.lo, 0),
+            flat(lanes.left, INVALID_BASE), flat(lanes.right, INVALID_BASE),
+            flat(lanes.valid, False))
+
+
+def compact_lanes(valid, size: int, *lanes):
+    """The valid entries of flat lanes, in order, packed into `size` slots.
+
+    Contig-scale rows are padded to contig_cap x max_contig_len (~10^8
+    positions at pipeline widths) while they hold ~10^6 k-mers; a sort over
+    the padded lanes costs minutes of TPU compile and a pass over every
+    pad.  Returns (lanes, valid, overflow): `lanes` keep the first
+    min(n, size) valid entries in their original order (the rest of each
+    lane is filler, `valid` False), and `overflow` is n > size.  Lanes
+    no longer than `size` pass through unchanged.
+    """
+    if valid.shape[0] <= size:
+        return lanes, valid, jnp.asarray(False)
+    n = valid.sum(dtype=jnp.int32)
+    idx = jnp.nonzero(valid, size=size, fill_value=0)[0]
+    return (tuple(x[idx] for x in lanes), jnp.arange(size) < n, n > size)
 
 
 def pseudo_count_table(bases, lengths, *, k: int, capacity: int,
@@ -70,19 +98,74 @@ def pseudo_count_table(bases, lengths, *, k: int, capacity: int,
     Local merge path and the Mesh owner exchange — the S=1 oracle test
     relies on both using exactly this weighting.
     """
+    return _pseudo_count_table(bases, lengths, k=k, capacity=capacity,
+                               weight=weight,
+                               backend=ops.resolve_backend(backend))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "capacity", "weight", "backend"))
+def _pseudo_count_table(bases, lengths, *, k: int, capacity: int,
+                        weight: int, backend: str) -> dict:
     seqs = ReadSet(
         bases=bases, lengths=lengths,
         mate=jnp.full(lengths.shape, -1, jnp.int32), insert_size=0,
     )
     hi, lo, left, right, valid = occurrences(seqs, k=k, backend=backend)
+    # a table of `capacity` keys holds no more distinct k-mers than that,
+    # and a contig set repeats few, so `capacity` occurrences bound the fold
+    (hi, lo, left, right), valid, cut = compact_lanes(
+        valid, capacity, hi, lo, left, right
+    )
     tab = count_occurrences(hi, lo, left, right, valid, capacity=capacity)
     w = jnp.int32(weight)
     return {
         **tab,
+        "overflow": tab["overflow"] | cut,
         "count": tab["count"] * w,
         "left_cnt": tab["left_cnt"] * w,
         "right_cnt": tab["right_cnt"] * w,
     }
+
+
+def merge_contig_kmers(tab: dict, bases, lengths, *, k: int, capacity: int,
+                       weight: int, backend=None) -> dict:
+    """`merge_counts(tab, pseudo_count_table(bases, lengths, ...))` in one
+    fold (§II-H).
+
+    The contig occurrences join the merge as rows of weight `weight`
+    instead of through a count table of their own: the sums per key are
+    the same, with one sort fewer to compile and run.  `overflow` also
+    flags contig occurrences cut at `capacity`.
+    """
+    return _merge_contig_kmers(tab, bases, lengths, k=k, capacity=capacity,
+                               weight=weight,
+                               backend=ops.resolve_backend(backend))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "capacity", "weight", "backend"))
+def _merge_contig_kmers(tab, bases, lengths, *, k: int, capacity: int,
+                        weight: int, backend: str) -> dict:
+    seqs = ReadSet(
+        bases=bases, lengths=lengths,
+        mate=jnp.full(lengths.shape, -1, jnp.int32), insert_size=0,
+    )
+    hi, lo, left, right, valid = occurrences(seqs, k=k, backend=backend)
+    (hi, lo, left, right), valid, cut = compact_lanes(
+        valid, capacity, hi, lo, left, right
+    )
+    valid = valid & (weight > 0)
+    w = jnp.where(valid, jnp.int32(weight), 0)
+    bases4 = jnp.arange(4, dtype=jnp.uint8)[None, :]
+    hist = lambda ext: jnp.where(ext[:, None] == bases4, w[:, None], 0)
+    cat = lambda a, b: jnp.concatenate([a, b])
+    out = aggregate_weighted(
+        cat(tab["hi"], hi), cat(tab["lo"], lo), cat(tab["count"], w),
+        cat(tab["left_cnt"], hist(left)), cat(tab["right_cnt"], hist(right)),
+        cat(tab["count"] > 0, valid), capacity=capacity,
+    )
+    return {**out, "overflow": out["overflow"] | cut}
 
 
 def _group_segments(shi, slo, sv):
@@ -104,13 +187,17 @@ def count_occurrences(hi, lo, left, right, valid, *, capacity: int):
     packed at the front in sorted key order.  `n_unique` may exceed
     `capacity` — callers must check `overflow`.
     """
-    # push invalids to the end of the sort order
+    # push invalids to the end of the sort order; a valid key never equals
+    # EMPTY_HI, so validity comes back from the sorted key.  The extension
+    # bases ride as one packed payload, and the sort need not be stable:
+    # counts and histograms do not depend on the order within a key, and
+    # XLA:TPU compiles a sort much faster with fewer operands.
     shi = jnp.where(valid, hi, EMPTY_HI)
     slo = jnp.where(valid, lo, jnp.uint32(0xFFFFFFFF))
-    shi, slo, sl, sr, sv = jax.lax.sort(
-        (shi, slo, left, right, valid.astype(jnp.uint8)), num_keys=2
-    )
-    sv = sv.astype(bool)
+    ext = (left.astype(jnp.int32) << 8) | right.astype(jnp.int32)
+    shi, slo, ext = jax.lax.sort((shi, slo, ext), num_keys=2, is_stable=False)
+    sl, sr = ext >> 8, ext & 0xFF
+    sv = shi != EMPTY_HI
     new_grp, seg, n_unique = _group_segments(shi, slo, sv)
     # invalid rows scatter out of bounds (dropped)
     seg_d = jnp.where(sv, seg, capacity)
@@ -118,10 +205,10 @@ def count_occurrences(hi, lo, left, right, valid, *, capacity: int):
     # extension histograms; ext >= 4 (absent) dropped
     lseg = jnp.where(sv & (sl < 4), seg, capacity)
     rseg = jnp.where(sv & (sr < 4), seg, capacity)
-    lcnt = jnp.zeros((capacity, 4), jnp.int32).at[lseg, sl.astype(jnp.int32) & 3].add(
+    lcnt = jnp.zeros((capacity, 4), jnp.int32).at[lseg, sl & 3].add(
         1, mode="drop"
     )
-    rcnt = jnp.zeros((capacity, 4), jnp.int32).at[rseg, sr.astype(jnp.int32) & 3].add(
+    rcnt = jnp.zeros((capacity, 4), jnp.int32).at[rseg, sr & 3].add(
         1, mode="drop"
     )
     out_hi = jnp.full((capacity,), EMPTY_HI, jnp.uint32)
@@ -152,10 +239,9 @@ def aggregate_weighted(hi, lo, cnt, lcnt, rcnt, valid, *, capacity: int) -> dict
     shi = jnp.where(valid, hi, EMPTY_HI)
     slo = jnp.where(valid, lo, jnp.uint32(0xFFFFFFFF))
     idx = jnp.arange(hi.shape[0], dtype=jnp.int32)
-    shi, slo, sv_u8, perm = jax.lax.sort(
-        (shi, slo, valid.astype(jnp.uint8), idx), num_keys=2
-    )
-    sv = sv_u8.astype(bool)
+    # as in count_occurrences: sums are order-free within a key
+    shi, slo, perm = jax.lax.sort((shi, slo, idx), num_keys=2, is_stable=False)
+    sv = shi != EMPTY_HI
     cnt, lcnt, rcnt = cnt[perm], lcnt[perm], rcnt[perm]
     new_grp, seg, n_unique = _group_segments(shi, slo, sv)
     seg_d = jnp.where(sv, seg, capacity)
@@ -178,6 +264,7 @@ def aggregate_weighted(hi, lo, cnt, lcnt, rcnt, valid, *, capacity: int) -> dict
     }
 
 
+@functools.partial(jax.jit, static_argnames=("capacity",))
 def merge_counts(a: dict, b: dict, *, capacity: int) -> dict:
     """Union two count tables (same k), summing histograms (§II-H)."""
     return aggregate_weighted(
@@ -316,6 +403,7 @@ def analyze(
     return finalize(tab, min_count=min_count, policy=policy)
 
 
+@functools.partial(jax.jit, static_argnames=("min_count", "policy"))
 def finalize(tab: dict, *, min_count: int, policy: ExtensionPolicy) -> KmerSet:
     """Apply the count floor and extension policy to a raw count table."""
     used = tab["count"] >= min_count

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from repro.kernels import ops
 
 from . import dht, kmer
-from .types import ContigSet, ReadSet
+from .types import INVALID_BASE, ContigSet, ReadSet
 
 NONE = jnp.int32(-1)
 
@@ -58,6 +58,7 @@ class WalkTables(NamedTuple):
     left_hist: tuple
 
 
+@jax.jit
 def localize_reads(reads: ReadSet, aln_contig):
     """Read -> contig assignment: own alignment, else the mate's (§II-G)."""
     own = aln_contig
@@ -117,18 +118,31 @@ def accumulate_walk_tables(
     kernel path (`kernels.ops`), which emits the canonical codes and
     canonicalized extensions in one pass.
     """
+    return _accumulate_walk_tables(
+        wt, reads, read_contig, mer_sizes=tuple(mer_sizes), tag_bits=tag_bits,
+        backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("mer_sizes", "tag_bits", "backend"))
+def _accumulate_walk_tables(wt, reads, read_contig, *, mer_sizes: tuple,
+                            tag_bits: int, backend: str) -> WalkTables:
     tables, lhs, rhs = [], [], []
+    # every rung inserts [R * Lp] lanes (the last m - 1 of a row and the
+    # tiling pad invalid, kmer.flat_lanes), so the rungs' inserts share a
+    # shape
+    tag = kmer.flat_lanes(
+        jnp.broadcast_to(read_contig[:, None], reads.bases.shape), 0)
     for rung, m in enumerate(mer_sizes):
         lanes = ops.kmer_extract(reads.bases, reads.lengths, k=m,
                                  backend=backend)
-        W = reads.max_len - m + 1
-        tag = jnp.broadcast_to(read_contig[:, None], (reads.num_reads, W))
-        v = lanes.valid[:, :W] & (read_contig[:, None] >= 0)
-        flat = lambda x: x.reshape((-1,))
+        v = lanes.valid & (read_contig[:, None] >= 0)
         t, lh, rh = _count_tagged(
-            flat(lanes.hi[:, :W]), flat(lanes.lo[:, :W]),
-            flat(lanes.left[:, :W]), flat(lanes.right[:, :W]), flat(v),
-            flat(tag), m=m, tag_bits=tag_bits,
+            kmer.flat_lanes(lanes.hi, 0), kmer.flat_lanes(lanes.lo, 0),
+            kmer.flat_lanes(lanes.left, INVALID_BASE),
+            kmer.flat_lanes(lanes.right, INVALID_BASE),
+            kmer.flat_lanes(v, False), tag, m=m, tag_bits=tag_bits,
             table=wt.tables[rung], lh=wt.left_hist[rung],
             rh=wt.right_hist[rung], backend=backend,
         )
@@ -274,6 +288,16 @@ def extend_with_tables(
     built in one shot) and the streaming path (tables accumulated batch by
     batch, DESIGN.md §7).
     """
+    return _extend_with_tables(
+        wt, contigs, alive, mer_sizes=tuple(mer_sizes), max_ext=max_ext,
+        min_len=min_len, backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("mer_sizes", "max_ext",
+                                             "min_len", "backend"))
+def _extend_with_tables(wt, contigs, alive, *, mer_sizes: tuple, max_ext: int,
+                        min_len, backend: str):
     C = contigs.capacity
     tag_bits = min(16, 62 - 2 * max(mer_sizes))
     assert C <= (1 << tag_bits), (

@@ -142,7 +142,10 @@ def aggregate_links(ea, eb, gap, valid, is_splint, *, capacity: int) -> Links:
     key_a = jnp.where(valid, ea, BIG)
     key_b = jnp.where(valid, eb, BIG)
     idx = jnp.arange(ea.shape[0], dtype=jnp.int32)
-    ka, kb, perm = jax.lax.sort((key_a, key_b, idx), num_keys=2)
+    # idx as the last key gives a stable sort's order from an unstable
+    # sort, which XLA:TPU compiles faster
+    ka, kb, perm = jax.lax.sort((key_a, key_b, idx), num_keys=3,
+                                is_stable=False)
     g = gap[perm]
     sp = is_splint[perm]
     v = valid[perm]
@@ -173,6 +176,7 @@ def aggregate_links(ea, eb, gap, valid, is_splint, *, capacity: int) -> Links:
     )
 
 
+@jax.jit
 def candidate_links(al, reads: ReadSet, contig_lengths):
     """Per-read link witnesses: splints + spans as flat candidate arrays.
 
@@ -192,6 +196,7 @@ def candidate_links(al, reads: ReadSet, contig_lengths):
     return ea, eb, gap, valid, is_splint
 
 
+@functools.partial(jax.jit, static_argnames=("capacity", "min_support"))
 def links_from_candidates(ea, eb, gap, valid, is_splint, alive, *,
                           capacity: int, min_support: int = 2) -> Links:
     """Aggregate candidate witnesses into the link store (contig scale)."""
@@ -227,7 +232,9 @@ def _per_end_links(links: Links, n_ends: int):
     key_end = jnp.where(v, ends, BIG)
     # sort by (end, gap): quantize gap into the sort key
     gap_q = jnp.clip(gaps, -1e6, 1e6).astype(jnp.float32)
-    sk_end, sk_gap, s_lidx = jax.lax.sort((key_end, gap_q, lidx), num_keys=2)
+    # lidx is unique per end, so as the last key it keeps the stable order
+    sk_end, sk_gap, s_lidx = jax.lax.sort((key_end, gap_q, lidx), num_keys=3,
+                                          is_stable=False)
     first = jnp.concatenate([jnp.ones((1,), bool), sk_end[1:] != sk_end[:-1]])
     # rank within the end group
     seg = jnp.cumsum(first.astype(jnp.int32)) - 1
@@ -407,6 +414,7 @@ def form_scaffolds(matched_end, end_gap, alive, *, n_contigs: int,
     )
 
 
+@functools.partial(jax.jit, static_argnames=("max_members",))
 def scaffold_from_links(
     links: Links,
     contigs: ContigSet,
@@ -422,7 +430,8 @@ def scaffold_from_links(
     C = contigs.capacity
     n_ends = 2 * C
     links, suspended = suspend_repeats(
-        links, contigs.lengths, float(insert_size), n_ends
+        links, contigs.lengths, jnp.asarray(insert_size, jnp.float32),
+        n_ends
     )
     if hmm_hit is None:
         hmm_hit = jnp.zeros((C,), bool)

@@ -35,7 +35,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import alignment, bloom, exchange, kmer_analysis, \
     local_assembly
@@ -152,12 +151,12 @@ def sharded_kmer_analysis(
         return kset, res.overflow, table_ovf
 
     in_specs = (P(AXIS), P(AXIS)) + (P(AXIS),) * len(contig_args)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(AXIS), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(reads.bases, reads.lengths, *contig_args)
 
@@ -235,12 +234,12 @@ def sharded_bloom_observe(
         table_ovf = jax.lax.psum(pre["overflow"].astype(jnp.int32), AXIS)
         return f1.bits[None], f2.bits[None], res.overflow, table_ovf
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(reads.bases, reads.lengths, f1_bits, f2_bits)
 
@@ -321,12 +320,12 @@ def sharded_stream_fold(
                 new["right_cnt"], occ_total, occ_admitted, res.overflow,
                 table_ovf)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS),) * 8,
         out_specs=(P(AXIS),) * 5 + (P(),) * 4,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(reads.bases, reads.lengths, f2_bits,
              run["hi"], run["lo"], run["count"], run["left_cnt"],
@@ -382,12 +381,12 @@ def sharded_align(
             gapped=gapped, backend=backend,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS)) + (P(),) * 11,
         out_specs=P(AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         sharded.bases, sharded.lengths,
@@ -443,12 +442,12 @@ def localize_with(
         return (rb, rl) + tuple(routed[2:]) + (rv, total_ovf)
 
     n_pl = len(payload)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS),) * (4 + n_pl),
         out_specs=(P(AXIS),) * (3 + n_pl) + (P(),),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(sharded.bases, sharded.lengths, sharded.valid, dest_contig,
              *payload)
@@ -524,12 +523,12 @@ def sharded_extend(
         )
         return ext.bases, ext.lengths, ext.depths
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS)) + (P(),) * 4,
         out_specs=(P(AXIS), P(AXIS), P(AXIS)),
-        check_rep=False,
+        check_vma=False,
     )
     eb, el, ed = fn(
         localized.bases, localized.lengths, eff_loc,
@@ -618,12 +617,12 @@ def sharded_link_candidates(
         )
         return candidate_links(al_loc, local, clens_rep)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS),) * 8 + (P(),),
         out_specs=(P(AXIS),) * 5,
-        check_rep=False,
+        check_vma=False,
     )
     cands = fn(
         localized.bases, localized.lengths, localized.valid,

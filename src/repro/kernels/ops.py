@@ -3,11 +3,15 @@
 Every compute hot-spot with a Pallas kernel is fronted here by a *backend*
 choice (DESIGN.md §8):
 
-  * ``"pallas"`` — the fused kernel.  On TPU it compiles natively; on any
-    other backend it runs in interpret mode (the kernel body still executes
-    exactly, op for op), so the same call sites work everywhere.
   * ``"ref"``    — the pure-jnp oracle in `kernels.ref`, kept bit-identical
     (for integer kernels) or numerically validated (for float kernels).
+    The default on every platform, TPU included.
+  * ``"pallas"`` — the fused kernel.  On TPU it is handed to Mosaic, and
+    none of the assembler kernels lowers for v5e yet (rank-1 8-row
+    BlockSpecs, cumsum, 1-D table gathers, dynamic_slice): Mosaic's error
+    propagates, nothing falls back.  On any other backend it runs in
+    interpret mode (the kernel body still executes exactly, op for op),
+    which is how the parity suites exercise it.
 
 Selection order, strongest first:
 
@@ -15,9 +19,7 @@ Selection order, strongest first:
      the whole process without touching plans or code);
   2. the explicit ``backend=`` argument (plumbed from
      ``AssemblyPlan.kernel_backend`` through the execution contexts);
-  3. the hardware-aware default (`default_backend`): ``"pallas"`` on TPU,
-     ``"ref"`` elsewhere — the backends are bit-identical, and off-TPU the
-     kernel only runs through the interpreter.
+  3. the default (`default_backend`): ``"ref"``.
 
 The k-mer extraction path (`kmer_extract`) is THE system ingest hot path:
 all extraction/canonicalization/hashing in core/, stream/, and dist/ goes
@@ -55,17 +57,16 @@ ENV_VAR = "REPRO_KERNELS"
 
 
 def default_backend() -> str:
-    """Hardware-aware default: the fused kernel where it compiles natively.
+    """The default backend: the jnp ref, on every platform.
 
-    On TPU the Pallas kernel is the point of this package; on every other
-    backend it would run through the interpreter — same bits, pure
-    overhead (~1.5x, measured by benchmarks/bench_kernels.py) — so the
-    bit-identical jnp ref serves the default there.  Force `pallas` via
-    REPRO_KERNELS or `AssemblyPlan.kernel_backend` to exercise the kernel
-    path off-TPU (CI's parity tests and the kernels bench do exactly
-    that).
+    A kernel returns to the default only once it lowers for the chip AND
+    beats the ref in every benchmark cell that runs it; today none lowers
+    for v5e (tests/test_v5e_compile.py compiles the ref path instead).
+    Off-TPU the kernels only interpret.  Force `pallas` via REPRO_KERNELS
+    or `AssemblyPlan.kernel_backend` to exercise the kernel path (the
+    parity tests and the kernels bench do exactly that).
     """
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "ref"
 
 
 def resolve_backend(backend=None) -> str:
@@ -97,6 +98,7 @@ def resolve_backend(backend=None) -> str:
 
 
 def _interpret() -> bool:
+    """Interpret Pallas kernels off-TPU; on TPU always compile natively."""
     return jax.default_backend() != "tpu"
 
 
@@ -160,8 +162,9 @@ def mer_walk(
     streaming driver — all land here.  `wt` is a
     `local_assembly.WalkTables`-shaped record (tuples of per-rung
     `dht.HashTable`s plus right/left extension histograms, one rung per
-    entry of `mer_sizes`); it is normalized into stacked [n_rungs, ...]
-    arrays so both backends consume one form.
+    entry of `mer_sizes`).  The ref walk indexes the per-rung tuples as
+    they are; only the Pallas kernel gets them stacked into [n_rungs, ...]
+    arrays — a copy of every table, several GB at pipeline widths.
 
     Pass `target_hi/lo` + `seed_len` > 0 for the gap-closing variant: a
     walker whose buffer suffix reaches the target seed records
@@ -172,14 +175,14 @@ def mer_walk(
     assert len(wt.tables) == n, (len(wt.tables), mer_sizes)
     cap = wt.tables[0].capacity
     assert all(t.capacity == cap for t in wt.tables), "rung capacity mismatch"
-    keys_hi = jnp.stack([t.slot_hi for t in wt.tables])
-    keys_lo = jnp.stack([t.slot_lo for t in wt.tables])
-    used = jnp.stack([t.used for t in wt.tables])
-    max_probe = jnp.stack(
-        [jnp.asarray(t.max_probe, jnp.int32) for t in wt.tables]
+    tables = (
+        tuple(t.slot_hi for t in wt.tables),
+        tuple(t.slot_lo for t in wt.tables),
+        tuple(t.used for t in wt.tables),
+        tuple(jnp.asarray(t.max_probe, jnp.int32) for t in wt.tables),
+        tuple(wt.right_hist),
+        tuple(wt.left_hist),
     )
-    rh = jnp.stack(list(wt.right_hist))
-    lh = jnp.stack(list(wt.left_hist))
     has_target = target_hi is not None
     if has_target:
         assert seed_len > 0, "target walk needs seed_len > 0"
@@ -193,13 +196,12 @@ def mer_walk(
     kw = dict(mer_sizes=tuple(mer_sizes), tag_bits=tag_bits, max_ext=max_ext,
               min_votes=min_votes, dominance=dominance, seed_len=seed_len)
     if b == "ref":
-        return ref.mer_walk_ref(*args, keys_hi, keys_lo, used, max_probe,
-                                rh, lh, **kw)
+        return ref.mer_walk_ref(*args, *tables, **kw)
     pad = (-E) % BLOCK_WALKERS
     if pad:
         zeros = lambda x: jnp.zeros((pad,), x.dtype)
         args = [jnp.concatenate([x, zeros(x)]) for x in args]
-    out = _mw.mer_walk(*args, keys_hi, keys_lo, used, max_probe, rh, lh,
+    out = _mw.mer_walk(*args, *(jnp.stack(t) for t in tables),
                        interpret=_interpret(), **kw)
     if pad:
         out = MerWalkOut(*(x[:E] for x in out))

@@ -77,8 +77,10 @@ def mer_walk_ref(
     full-set jnp gathers through `core.dht.lookup` and the `core.kmer`
     codec), extended with the inline target-seed check the fused kernel
     performs for gap closing, and kept BIT-identical to the Pallas kernel
-    (tests/test_walk_parity.py).  It takes the same stacked per-rung table
-    arrays as the kernel so both backends see one normal form.
+    (tests/test_walk_parity.py).  The per-rung table arguments are either
+    the kernel's stacked [n_rungs, ...] arrays or tuples of per-rung arrays
+    (what `ops.mer_walk` passes, so the tables are never copied): only
+    `x[r]` is taken of them.
     """
     E = start_hi.shape[0]
     n_rungs = len(mer_sizes)
@@ -188,6 +190,7 @@ def mer_walk_ref(
                       hit_pos=hit_pos)
 
 
+@jax.jit
 def dht_lookup_ref(slot_hi, slot_lo, used, max_probe, hi, lo, valid):
     """Oracle for kernels.dht_probe.dht_lookup: `core.dht.lookup_jnp`.
 
@@ -199,8 +202,13 @@ def dht_lookup_ref(slot_hi, slot_lo, used, max_probe, hi, lo, valid):
     return dht.lookup_jnp(table, hi, lo, valid)
 
 
+@jax.jit
 def dht_insert_ref(slot_hi, slot_lo, used, max_probe, hi, lo, valid):
-    """Oracle for kernels.dht_probe.dht_insert: `core.dht.insert_jnp`."""
+    """Oracle for kernels.dht_probe.dht_insert: `core.dht.insert_jnp`.
+
+    Both DHT refs are jitted: run eagerly, their probe `while_loop` would
+    be traced and compiled anew on every call (TPU compiles of it take
+    seconds), where a jitted call reuses one program per shape."""
     table = dht.HashTable(slot_hi=slot_hi, slot_lo=slot_lo, used=used,
                           max_probe=max_probe)
     out, slots = dht.insert_jnp(table, hi, lo, valid)

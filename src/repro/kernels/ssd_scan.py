@@ -94,6 +94,4 @@ def pltpu_scratch(H, P, N):
 def _seq_grid_params():
     from jax.experimental.pallas import tpu as pltpu
 
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return params_cls(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))

@@ -162,3 +162,33 @@ def test_sharded_kmer_analysis_contig_injection_single_shard_oracle():
                                   np.asarray(ref.count)[ref_used])
     np.testing.assert_array_equal(np.asarray(kset.left_ext)[got_used],
                                   np.asarray(ref.left_ext)[ref_used])
+
+
+def test_merge_contig_kmers_equals_merge_of_pseudo_table():
+    """Local's one-fold §II-H merge (contig occurrences as weighted rows)
+    gives the table that counting the contigs apart and merging gives."""
+    from repro.core.types import ContigSet
+
+    genome, reads, _ = mgsim.single_genome_reads(5, genome_len=300,
+                                                 coverage=15)
+    bases = np.full((4, 512), 4, np.uint8)
+    bases[0, :300] = np.asarray(genome)
+    bases[2, :150] = np.asarray(genome)[75:225]   # a repeated stretch
+    contigs = ContigSet(jnp.asarray(bases),
+                        jnp.asarray([300, 0, 150, 0], jnp.int32),
+                        jnp.ones((4,), jnp.float32))
+    tab = kmer_analysis.count_occurrences(
+        *kmer_analysis.occurrences(reads, k=21), capacity=1 << 12
+    )
+    want = kmer_analysis.merge_counts(
+        tab, kmer_analysis.pseudo_count_table(
+            contigs.bases, contigs.lengths, k=21, capacity=1 << 12, weight=4),
+        capacity=1 << 12,
+    )
+    got = kmer_analysis.merge_contig_kmers(
+        tab, contigs.bases, contigs.lengths, k=21, capacity=1 << 12, weight=4
+    )
+    for key in ("hi", "lo", "count", "left_cnt", "right_cnt", "n_unique",
+                "overflow"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)

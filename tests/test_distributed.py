@@ -37,7 +37,6 @@ def run_devices_script(body: str, ndev: int = 8, timeout: int = 600) -> str:
 def test_exchange_route_roundtrip_8dev():
     run_devices_script(
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.core import exchange
         import functools
@@ -55,8 +54,8 @@ def test_exchange_route_roundtrip_8dev():
             )
             return res.payload[0], res.valid, res.overflow
 
-        fn = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P("data"), P("data"), P()), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                       out_specs=(P("data"), P("data"), P()), check_vma=False)
         got, valid, ovf = fn(vals, dest)
         got, valid = np.asarray(got), np.asarray(valid)
         assert int(ovf) == 0, f"overflow {ovf}"

@@ -151,3 +151,16 @@ def test_first_last_base():
     hi, lo = kmer.pack_window(bases, k=k)
     assert int(kmer.first_base(hi, lo, k=k)[0]) == BASES.index("G")
     assert int(kmer.last_base(hi, lo, k=k)[0]) == BASES.index("T")
+
+
+@pytest.mark.parametrize("width", [150, 128, 1])
+def test_flat_lanes_pads_rows_to_the_lane_tiling(width):
+    """Rows are padded to a multiple of LANE_TILE with the fill value, then
+    flattened row-major; a row already on the tiling is not padded."""
+    x = jnp.arange(3 * width, dtype=jnp.int32).reshape(3, width)
+    out = np.asarray(kmer.flat_lanes(x, -1))
+    padded = -(-width // kmer.LANE_TILE) * kmer.LANE_TILE
+    assert out.shape == (3 * padded,)
+    rows = out.reshape(3, padded)
+    np.testing.assert_array_equal(rows[:, :width], np.asarray(x))
+    assert (rows[:, width:] == -1).all()

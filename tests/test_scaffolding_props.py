@@ -9,7 +9,8 @@ fixtures:
     consistent with the members' orientations (exit end of the left member
     paired with the entry end of the right member);
   * rendered scaffolds contain each member's oriented bases verbatim at
-    its offset; unclosed gaps render as N runs; gap-closed sequences keep
+    its offset; flanks that overlap join on the overlap, read errors
+    and all; other unclosed gaps render as N runs; gap-closed sequences keep
     both flanking contig ends verbatim with a non-N walk fill between
     them.
 """
@@ -157,6 +158,52 @@ def test_render_members_verbatim_open_gap_is_n_run():
     np.testing.assert_array_equal(out[: len(left)], left)
     np.testing.assert_array_equal(out[len(left): len(left) + 23], 4)
     np.testing.assert_array_equal(out[len(left) + 23:], right)
+
+
+def _overlap_pair(seed, overlap, errors):
+    """Contigs [genome[:250], rc(genome[250 - overlap:400])] with read
+    errors at the given offsets of the second contig's overlapping head."""
+    genome = mgsim.random_genome(np.random.default_rng(seed), 450)
+    left, right = genome[:250], genome[250 - overlap: 400].copy()
+    for e in errors:
+        right[e] = (right[e] + 1) % 4
+    # contig 1 is stored reverse-complemented; the scaffold flips it back
+    contigs = _contig_set([left, mgsim.rc_np(right)])
+    mer_sizes = (17, 21, 25)
+    wt = local_assembly.empty_walk_tables(mer_sizes=mer_sizes, capacity=1 << 10)
+    seqs = gap_closing.close_and_render_with_tables(
+        _two_contig_scaffold(gap_est=-float(overlap) - 20), contigs, wt,
+        seed_len=17, mer_sizes=mer_sizes,
+    )
+    L = int(seqs.lengths[0])
+    return left, right, bool(seqs.closed[0, 0]), np.asarray(seqs.bases[0, :L])
+
+
+@pytest.mark.parametrize("overlap,errors", [
+    (17, ()), (90, ()), (148, ()),
+    (60, (0,)), (60, (59,)), (120, (3, 100)),
+])
+def test_overlapping_flanks_render_the_overlap_once(overlap, errors):
+    """Adjacent contigs whose ends overlap (k - 1 plus what local assembly
+    walked onto both) join on the overlap: no N run and no duplicated
+    bases, even with nothing to walk.  An overlap that differs in a read
+    error or two still joins, with the right flank's bases."""
+    left, right, closed, out = _overlap_pair(14, overlap, errors)
+    assert closed
+    np.testing.assert_array_equal(
+        out, np.concatenate([left[: len(left) - overlap], right])
+    )
+
+
+def test_inexact_overlap_stays_an_n_run():
+    """An overlap that differs in more than a couple of bases is not
+    joined: both members render verbatim around a one-base N run."""
+    left, right, closed, out = _overlap_pair(15, 60, (5, 30, 55))
+    assert not closed
+    assert len(out) == len(left) + 1 + len(right)
+    np.testing.assert_array_equal(out[: len(left)], left)
+    assert out[len(left)] == 4
+    np.testing.assert_array_equal(out[len(left) + 1:], right)
 
 
 def test_closed_gap_keeps_flanking_ends_verbatim():
