@@ -2,7 +2,7 @@
 
 Multi-device benches cannot set XLA_FLAGS in-process (the orchestrator
 must keep the default single device), so they follow the same subprocess
-pattern as tests/test_distributed.py.  The children measure deterministic
+pattern as tests/helpers.py `run_devices_script`.  The children measure deterministic
 counts on host devices and always run on the CPU: a parent that has
 touched JAX holds the chip, and a child that opened it too would fail or
 hang.

@@ -20,7 +20,7 @@ genomes:
 
 With --chips 4 the script runs only `Mesh(num_shards=4)` and the `Local()`
 run it is compared with, at the tolerance of
-tests/test_distributed.py::test_mesh_assemble_matches_local.
+tests/test_mesh_vs_local.py::test_mesh_assemble_matches_local.
 
 Per-stage wall times are printed for information: one unrepeated run, with
 the compile time inside each stage (trace + lower + XLA compile, or the
@@ -224,7 +224,7 @@ def check_local(out, rep) -> list:
 
 
 def check_mesh(rep_l, out_m, rep_m) -> list:
-    """tests/test_distributed.py::test_mesh_assemble_matches_local."""
+    """tests/test_mesh_vs_local.py::test_mesh_assemble_matches_local."""
     failures = []
     if rep_m["genome_fraction"] < rep_l["genome_fraction"] - 0.02:
         failures.append(f"mesh genome fraction {rep_m['genome_fraction']:.4f}"

@@ -27,9 +27,16 @@ Algorithm 3 end to end on a mesh:
     per shard; only the contig-scale link store and matching replicate.
 
 Every stage reports overflow; nothing is silently dropped (§3.4).
+
+Each stage is one jitted program per static configuration (mesh, sizes,
+kernel backend): its `shard_map` is built inside the jit, so a later call
+with the same shapes reuses the compiled program.  The backend is
+resolved before the jit, as on the Local path, so REPRO_KERNELS still
+takes effect once a program is cached.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -41,6 +48,7 @@ from repro.core import alignment, bloom, exchange, kmer_analysis, \
 from repro.core.kmer_analysis import ExtensionPolicy
 from repro.core.scaffolding import candidate_links
 from repro.core.types import ContigSet, INVALID_BASE, ReadSet
+from repro.kernels import ops
 from . import capacity as cap_lib
 from .pipeline import AXIS, ShardedReads, kmer_owner, mesh_shards
 
@@ -83,6 +91,22 @@ def sharded_kmer_analysis(
     Returns (kset, route_overflow, table_overflow) exactly like
     `dist.pipeline.distributed_kmer_analysis`.
     """
+    return _sharded_kmer_analysis(
+        reads.bases, reads.lengths, prev_contigs, mesh=mesh, k=k,
+        pre_capacity=pre_capacity, capacity=capacity,
+        route_capacity=route_capacity, min_count=min_count, policy=policy,
+        contig_weight=contig_weight, backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "k", "pre_capacity", "capacity", "route_capacity", "min_count",
+    "policy", "contig_weight", "backend"))
+def _sharded_kmer_analysis(bases, lengths, prev_contigs, *, mesh, k: int,
+                           pre_capacity: int, capacity: int,
+                           route_capacity: Optional[int], min_count: int,
+                           policy: ExtensionPolicy, contig_weight: int,
+                           backend: str):
     S = mesh_shards(mesh)
     has_contigs = prev_contigs is not None
     if route_capacity is None:
@@ -92,8 +116,8 @@ def sharded_kmer_analysis(
         route_capacity = cap_lib.default_route_capacity(
             (2 if has_contigs else 1) * pre_capacity, S
         )
-    assert reads.bases.shape[0] % S == 0, (
-        f"reads rows {reads.bases.shape[0]} not divisible by {S}; "
+    assert bases.shape[0] % S == 0, (
+        f"reads rows {bases.shape[0]} not divisible by {S}; "
         f"use shard_reads"
     )
     contig_args = ()
@@ -158,7 +182,7 @@ def sharded_kmer_analysis(
         out_specs=(P(AXIS), P(), P()),
         check_vma=False,
     )
-    return fn(reads.bases, reads.lengths, *contig_args)
+    return fn(bases, lengths, *contig_args)
 
 
 # ---------------------------------------------------------------------------
@@ -192,6 +216,18 @@ def sharded_bloom_observe(
 
     Returns (f1_bits, f2_bits, route_overflow, table_overflow).
     """
+    return _sharded_bloom_observe(
+        batch, f1_bits, f2_bits, mesh=mesh, k=k, pre_capacity=pre_capacity,
+        route_capacity=route_capacity, num_hashes=num_hashes,
+        backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "k", "pre_capacity", "route_capacity", "num_hashes", "backend"))
+def _sharded_bloom_observe(batch, f1_bits, f2_bits, *, mesh, k: int,
+                           pre_capacity: int, route_capacity: Optional[int],
+                           num_hashes: int, backend: str):
     S = mesh_shards(mesh)
     from .pipeline import shard_reads
 
@@ -270,6 +306,19 @@ def sharded_stream_fold(
     Returns (run', (occ_total, occ_admitted), route_overflow,
     table_overflow).
     """
+    return _sharded_stream_fold(
+        batch, f2_bits, run, mesh=mesh, k=k, capacity=capacity,
+        pre_capacity=pre_capacity, route_capacity=route_capacity,
+        num_hashes=num_hashes, backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "k", "capacity", "pre_capacity", "route_capacity", "num_hashes",
+    "backend"))
+def _sharded_stream_fold(batch, f2_bits, run, *, mesh, k: int, capacity: int,
+                         pre_capacity: int, route_capacity: Optional[int],
+                         num_hashes: int, backend: str):
     S = mesh_shards(mesh)
     from .pipeline import shard_reads
 
@@ -358,9 +407,19 @@ def sharded_align(
     form of merAligner's remote-bucket cache.  Output arrays are in the
     global sharded layout, usable directly as full [R, 2] alignments.
     """
+    return _sharded_align(
+        sharded, contigs, sidx, mesh=mesh,
+        insert_size=int(sharded.insert_size), seed_len=seed_len,
+        stride=stride, gapped=gapped, backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "insert_size", "seed_len", "stride", "gapped", "backend"))
+def _sharded_align(sharded, contigs, sidx, *, mesh, insert_size: int,
+                   seed_len: int, stride: int, gapped: bool, backend: str):
     S = mesh_shards(mesh)
     assert sharded.num_reads % S == 0
-    insert_size = int(sharded.insert_size)
     table = sidx.table
 
     def body(bases, lengths, slot_hi, slot_lo, used, max_probe,
@@ -417,14 +476,30 @@ def localize_with(
     routed payload tuple, overflow) — overflow counts reads cut at either
     the route lanes or the receiver block, reported per §3.4.
     """
+    rb, rl, rv, routed_pl, overflow = _localize_with(
+        sharded.bases, sharded.lengths, sharded.valid, dest_contig,
+        tuple(payload), mesh=mesh, out_factor=out_factor,
+    )
+    localized = ShardedReads(
+        bases=rb,
+        lengths=rl,
+        mate=jnp.full(rl.shape, -1, jnp.int32),
+        insert_size=sharded.insert_size,
+        valid=rv,
+    )
+    return localized, routed_pl, overflow
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "out_factor"))
+def _localize_with(bases, lengths, valid, dest_contig, payload, *, mesh,
+                   out_factor: int):
     S = mesh_shards(mesh)
-    R = sharded.num_reads
+    R = bases.shape[0]
     assert R % S == 0
     per = R // S
     out_per = out_factor * per
     route_cap = min(per, -(-2 * out_per // S))
     dest_contig = jnp.asarray(dest_contig, jnp.int32)[:R]
-    insert_size = int(sharded.insert_size)
 
     def body(bases, lengths, valid, dc, *pl):
         me = jax.lax.axis_index(AXIS)
@@ -449,19 +524,11 @@ def localize_with(
         out_specs=(P(AXIS),) * (3 + n_pl) + (P(),),
         check_vma=False,
     )
-    out = fn(sharded.bases, sharded.lengths, sharded.valid, dest_contig,
-             *payload)
+    out = fn(bases, lengths, valid, dest_contig, *payload)
     rb, rl = out[0], out[1]
     routed_pl = out[2:2 + n_pl]
     rv, overflow = out[2 + n_pl], out[3 + n_pl]
-    localized = ShardedReads(
-        bases=rb,
-        lengths=rl,
-        mate=jnp.full((S * out_per,), -1, jnp.int32),
-        insert_size=insert_size,
-        valid=rv,
-    )
-    return localized, routed_pl, overflow
+    return rb, rl, rv, routed_pl, overflow
 
 
 # ---------------------------------------------------------------------------
@@ -491,6 +558,20 @@ def sharded_extend(
     block only and extends only the contig rows it owns; the extended
     rows then combine by ownership.  Returns (ContigSet, overflow).
     """
+    return _sharded_extend(
+        sharded, contigs, alive, al, mesh=mesh,
+        insert_size=int(sharded.insert_size), mer_sizes=tuple(mer_sizes),
+        capacity=capacity, max_ext=max_ext, out_factor=out_factor,
+        backend=ops.resolve_backend(backend),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "insert_size", "mer_sizes", "capacity", "max_ext", "out_factor",
+    "backend"))
+def _sharded_extend(sharded, contigs, alive, al, *, mesh, insert_size: int,
+                    mer_sizes: tuple, capacity: int, max_ext: int,
+                    out_factor: int, backend: str):
     S = mesh_shards(mesh)
     C = contigs.capacity
     R = sharded.num_reads
@@ -504,8 +585,6 @@ def sharded_extend(
     localized, (eff_loc,), overflow = localize_with(
         sharded, eff, (eff,), mesh, out_factor=out_factor
     )
-    insert_size = int(sharded.insert_size)
-    mer_sizes = tuple(mer_sizes)
 
     def body(bases, lengths, eff_c, cbases, clens, cdepths, calive):
         me = jax.lax.axis_index(AXIS)
@@ -567,12 +646,19 @@ def sharded_link_candidates(
     returned flat witness arrays are already in global layout for
     `links_from_candidates`.
     """
+    return _sharded_link_candidates(
+        sharded, al, contigs, alive, mesh=mesh,
+        insert_size=int(sharded.insert_size), out_factor=out_factor,
+    )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "insert_size", "out_factor"))
+def _sharded_link_candidates(sharded, al, contigs, alive, *, mesh,
+                             insert_size: int, out_factor: int):
     S = mesh_shards(mesh)
     R = sharded.num_reads
     assert R % S == 0
-    per = R // S
-    out_per = out_factor * per
-    insert_size = int(sharded.insert_size)
 
     aln = jnp.asarray(al.contig[:, :2], jnp.int32)[:R]
     mate = jnp.asarray(sharded.mate, jnp.int32)[:R]

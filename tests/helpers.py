@@ -1,5 +1,21 @@
-"""Shared test utilities: genome/contig comparison oracles."""
+"""Shared test utilities: genome/contig comparison oracles, and the runner
+of scripts that need 8 host devices."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# Time limit of one `run_devices_script` call: about three times its
+# slowest caller (`test_mesh_walk_backend_parity`, 115 s on 8 CPU cores
+# beside five other test workers), so a Mesh stage that starts compiling
+# per call again fails with its output instead of eating the whole
+# suite's clock.
+DEVICES_SCRIPT_TIMEOUT_S = 360
 
 _RC = np.array([3, 2, 1, 0, 4], dtype=np.uint8)
 
@@ -51,3 +67,44 @@ def genome_coverage(contigs_list, genome, w=30) -> float:
         return 0.0
     hit = sum(1 for i in range(n) if g[i : i + w] in windows)
     return hit / n
+
+
+def run_devices_script(body: str) -> str:
+    """Run `body` in a fresh interpreter that sees 8 CPU host devices.
+
+    XLA_FLAGS must be set before jax is imported, and the test workers'
+    own jax sees one device, so each multi-device test starts its own
+    interpreter.  Fails, showing whatever the script printed, if it exits
+    non-zero or runs past DEVICES_SCRIPT_TIMEOUT_S.
+    """
+    script = textwrap.dedent(
+        """
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import numpy as np
+        import jax
+        import jax.numpy as jnp
+        assert jax.device_count() == 8, jax.device_count()
+        """
+    ) + textwrap.dedent(body)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"{REPO}/src:{REPO}/tests:{REPO}"
+    try:
+        out = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            env=env, timeout=DEVICES_SCRIPT_TIMEOUT_S,
+        )
+    except subprocess.TimeoutExpired as e:
+        pytest.fail(
+            f"script ran past {DEVICES_SCRIPT_TIMEOUT_S} s\n"
+            f"STDOUT:\n{_decoded(e.stdout)}\nSTDERR:\n{_decoded(e.stderr)}"
+        )
+    assert out.returncode == 0, f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
+    return out.stdout
+
+
+def _decoded(captured) -> str:
+    # what a timed-out run captured comes back as bytes, whatever `text` says
+    if isinstance(captured, bytes):
+        return captured.decode(errors="replace")
+    return captured or ""

@@ -1,5 +1,5 @@
 """Single-device unit tests for repro.dist (the 8-device paths live in
-test_distributed.py; everything here runs on the default one-device env)."""
+test_mesh_*.py; everything here runs on the default one-device env)."""
 import numpy as np
 import jax.numpy as jnp
 

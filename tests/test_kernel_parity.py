@@ -10,7 +10,7 @@ layer to its contract:
     hashes, strand flips, and validity identical between backends;
   * pipeline-level: `assemble` and `assemble_stream` on Local produce
     bit-identical scaffolds under both backends.  (The Mesh(8) twin lives
-    in tests/test_distributed.py; combined with the existing
+    in tests/test_mesh_backend_parity.py; combined with the existing
     mesh-vs-local and stream-vs-memory parity tests, every context/path
     pair is pinned.)
 """
@@ -131,7 +131,7 @@ def test_plan_validates_kernel_backend():
 
 
 # ---------------------------------------------------------------------------
-# pipeline-level parity (Local; Mesh(8) twin in test_distributed.py)
+# pipeline-level parity (Local; Mesh(8) twin in test_mesh_backend_parity.py)
 # ---------------------------------------------------------------------------
 
 

@@ -19,7 +19,7 @@ contract:
     env override is consulted on each new hot path;
   * pipeline-level parity (assemble / assemble_stream / Mesh(8)) rides
     the existing suites in tests/test_kernel_parity.py and
-    tests/test_distributed.py, which now traverse these kernels.
+    tests/test_mesh_*.py, which now traverse these kernels.
 """
 import numpy as np
 import jax.numpy as jnp

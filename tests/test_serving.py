@@ -3,7 +3,7 @@
 The acceptance bar for the serving subsystem (ISSUE 7):
   * two streaming jobs multiplexed on ONE shared context produce
     bit-identical results to solo `assemble_stream` runs (the Mesh(8)
-    twin lives in tests/test_distributed.py);
+    twin lives in tests/test_mesh_serving.py);
   * a job killed mid-stream resumes after a server restart and finishes
     bit-identically (journal + per-job StreamCheckpoint);
   * admission control provably refuses an over-budget job and backfills

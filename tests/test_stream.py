@@ -3,7 +3,7 @@
 The acceptance bar for the streaming subsystem (ISSUE 3):
   * `assemble_stream` over >= 2 batches reproduces the in-memory path's
     scaffolds (here: bit-identically, on Local — the Mesh(8) twin lives
-    in tests/test_distributed.py);
+    in tests/test_mesh_vs_local.py);
   * `AssemblyPlan.from_stream(...).bytes()` does not grow with total
     read count;
   * the two-pass Bloom admission drops >= 90% of singleton-error k-mers

@@ -12,7 +12,7 @@ dispatch layer to its contract:
     gap-closing target-stop variant;
   * pipeline-level: `assemble` and `assemble_stream` on Local produce
     bit-identical scaffolds under both backends (the Mesh(8) twin is
-    `test_mesh_walk_backend_parity` in tests/test_distributed.py).
+    `test_mesh_walk_backend_parity` in tests/test_mesh_backend_parity.py).
 """
 import dataclasses
 
@@ -228,7 +228,7 @@ def test_walk_backend_parity_property():
 
 
 # ---------------------------------------------------------------------------
-# pipeline-level parity (Local; Mesh(8) twin in test_distributed.py)
+# pipeline-level parity (Local; Mesh(8) twin in test_mesh_backend_parity.py)
 # ---------------------------------------------------------------------------
 
 
