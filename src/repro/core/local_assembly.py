@@ -241,9 +241,29 @@ def contig_end_buffers(contigs: ContigSet, alive):
     )
 
 
+def _shift_right(x, shift, max_shift: int):
+    """Row r of `x` moved right by `shift[r]` in [0, max_shift] columns,
+    pad bases entering at the left: a barrel shifter, one static shift
+    selected per bit of `shift`, elementwise and without indices."""
+    R, W = x.shape
+    for b in range(max_shift.bit_length()):
+        k = 1 << b
+        moved = jnp.concatenate(
+            [jnp.full((R, min(k, W)), INVALID_BASE, x.dtype),
+             x[:, : max(W - k, 0)]], axis=1)
+        x = jnp.where((shift & k)[:, None] != 0, moved, x)
+    return x
+
+
 @functools.partial(jax.jit, static_argnames=())
 def apply_extensions(contigs: ContigSet, alive, walk: WalkResult):
-    """Graft the walked bases onto the contigs (left end RC'd back)."""
+    """Graft the walked bases onto the contigs (left end RC'd back).
+
+    Bases move by barrel shifts, not per-position gathers: XLA:TPU gathers
+    element by element, and one over all C x Lmax padded positions cost
+    seconds a call.  No scatter either: a uint8 scatter of the right walks
+    wrote wrong bases on a TPU v5e, though CPU runs matched.
+    """
     C, Lmax = contigs.bases.shape
     max_ext = walk.ext_bases.shape[1]
     lext = walk.ext_bases[:C]      # left walks (in RC frame)
@@ -253,20 +273,25 @@ def apply_extensions(contigs: ContigSet, alive, walk: WalkResult):
     L = contigs.lengths
     new_len = jnp.minimum(L + nL + nR, Lmax)
     i = jnp.arange(Lmax, dtype=jnp.int32)[None, :]
-    # zone 1: prepended bases = complement(lext[nL-1-i])
-    lidx = jnp.clip(nL[:, None] - 1 - i, 0, max_ext - 1)
-    z1 = kmer.complement_base(jnp.take_along_axis(lext, lidx, axis=1))
+
+    def widen(x):                  # [C, max_ext] -> [C, Lmax], pad right
+        return jnp.pad(x[:, :Lmax], ((0, 0), (0, max(Lmax - max_ext, 0))),
+                       constant_values=INVALID_BASE)
+
+    # zone 1, in the first max_ext columns: complement(lext[nL-1-i])
+    z1 = widen(kmer.complement_base(
+        _shift_right(lext, max_ext - nL, max_ext)[:, ::-1]))
     # zone 2: original bases shifted right by nL
-    oidx = jnp.clip(i - nL[:, None], 0, Lmax - 1)
-    z2 = jnp.take_along_axis(contigs.bases, oidx, axis=1)
-    # zone 3: appended bases
-    ridx = jnp.clip(i - nL[:, None] - L[:, None], 0, max_ext - 1)
-    z3 = jnp.take_along_axis(rext, ridx, axis=1)
+    z2 = _shift_right(contigs.bases, nL, max_ext)
+    # zone 3: appended bases, moved from column 0 to nL + L (a row with
+    # nL + L >= Lmax has no zone 3)
+    z3 = _shift_right(widen(rext), jnp.minimum(nL + L, Lmax - 1), Lmax - 1)
     out = jnp.where(
         i < nL[:, None],
         z1,
-        jnp.where(i < (nL + L)[:, None], z2, jnp.where(i < new_len[:, None], z3, 4)),
-    ).astype(jnp.uint8)
+        jnp.where(i < (nL + L)[:, None], z2,
+                  jnp.where(i < new_len[:, None], z3, INVALID_BASE)),
+    )
     out = jnp.where(alive[:, None], out, contigs.bases)
     new_len = jnp.where(alive, new_len, contigs.lengths)
     return ContigSet(bases=out, lengths=new_len, depths=contigs.depths)

@@ -1,6 +1,11 @@
 """Local assembly (mer-walking) extends contigs into read-covered flanks."""
+import math
+import re
+
 import numpy as np
+import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import alignment, local_assembly
 from repro.core.types import ContigSet
@@ -75,3 +80,121 @@ def test_walk_isolation_between_contigs():
     # contig A extends (reads cover its flank), contig B must not
     assert int(extended.lengths[0]) > 250
     assert int(extended.lengths[1]) == 250
+
+
+# --- the graft (apply_extensions) -------------------------------------------
+
+GRAFT_C, GRAFT_LMAX, GRAFT_EXT = 16, 256, 64
+
+
+def _numpy_graft(bases, lengths, alive, ext_bases, ext_len):
+    """Plain graft: RC'd left walk + contig + right walk, cut at Lmax, pad 4
+    after; dead rows untouched."""
+    C, Lmax = bases.shape
+    out, new_len = bases.copy(), lengths.copy()
+    for c in np.flatnonzero(alive):
+        nL, nR = ext_len[c], ext_len[C + c]
+        left = [3 - b if b < 4 else b for b in ext_bases[c, :nL][::-1]]
+        row = np.concatenate([np.asarray(left, np.uint8),
+                              bases[c, : lengths[c]],
+                              ext_bases[C + c, :nR]])[:Lmax]
+        out[c] = 4
+        out[c, : len(row)] = row
+        new_len[c] = len(row)
+    return out, new_len
+
+
+def _graft_case(case, seed):
+    C, Lmax, E = GRAFT_C, GRAFT_LMAX, GRAFT_EXT
+    if case == "rows_narrower_than_walk":
+        Lmax = E // 2
+    rng = np.random.default_rng(seed)
+    lengths = rng.integers(0, Lmax + 1, C).astype(np.int32)
+    ext_len = rng.integers(0, E + 1, 2 * C).astype(np.int32)
+    alive = rng.random(C) < 0.75
+    if case == "empty_contig":
+        lengths[: C // 2] = 0
+    elif case == "full_contig":       # growth clipped at Lmax
+        lengths[: C // 2] = Lmax
+    elif case == "left_walk_max":
+        ext_len[:C] = E
+    elif case == "right_walk_past_end":
+        lengths[:] = rng.integers(Lmax - E, Lmax + 1, C)
+        ext_len[C:] = E
+    elif case == "dead_rows":
+        alive[: C // 2] = False
+    elif case == "no_walk":
+        ext_len[:] = 0
+    alive[0] = True
+    # contig bases, then junk past each length: dead rows must keep it, live
+    # rows must end in pad bases
+    bases = rng.integers(0, 5, (C, Lmax)).astype(np.uint8)
+    ext_bases = rng.integers(0, 5, (2 * C, E)).astype(np.uint8)
+    depths = rng.random(C).astype(np.float32)
+    return bases, lengths, alive, ext_bases, ext_len, depths
+
+
+@pytest.mark.parametrize("case", [
+    "random", "empty_contig", "full_contig", "left_walk_max",
+    "right_walk_past_end", "dead_rows", "no_walk", "rows_narrower_than_walk",
+])
+def test_apply_extensions_matches_numpy_graft(case):
+    bases, lengths, alive, ext_bases, ext_len, depths = _graft_case(case, 7)
+    contigs = ContigSet(bases=jnp.asarray(bases), lengths=jnp.asarray(lengths),
+                        depths=jnp.asarray(depths))
+    walk = local_assembly.WalkResult(
+        ext_bases=jnp.asarray(ext_bases), ext_len=jnp.asarray(ext_len),
+        status=jnp.zeros(ext_len.shape, jnp.int32))
+    got = local_assembly.apply_extensions(contigs, jnp.asarray(alive), walk)
+    want_bases, want_len = _numpy_graft(bases, lengths, alive, ext_bases,
+                                        ext_len)
+    assert got.bases.dtype == jnp.uint8
+    np.testing.assert_array_equal(np.asarray(got.lengths), want_len)
+    np.testing.assert_array_equal(np.asarray(got.bases), want_bases)
+    np.testing.assert_array_equal(np.asarray(got.depths), depths)
+    got_bases = np.asarray(got.bases)
+    np.testing.assert_array_equal(got_bases[~alive], bases[~alive])
+    Lmax = bases.shape[1]
+    pad = np.arange(Lmax)[None, :] >= want_len[:, None]
+    assert (got_bases[alive & (want_len < Lmax)][
+        pad[alive & (want_len < Lmax)]] == 4).all()
+
+
+def _index_counts(hlo_text):
+    """(op, index tuples) of every gather and scatter in optimised HLO."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo_text))
+    counts = []
+    for op, indices, ivd in re.findall(
+            r"= \S+ (gather|scatter)\(%[\w.\-]+, (%[\w.\-]+).*?"
+            r"index_vector_dim=(\d+)", hlo_text):
+        dims = [int(d) for d in shapes[indices].split(",") if d]
+        n = math.prod(dims)
+        counts.append((op, n // dims[int(ivd)] if int(ivd) < len(dims) else n))
+    return counts
+
+
+def test_apply_extensions_indexes_no_padded_position():
+    """No gather or scatter of the graft indexes all C x Lmax positions:
+    XLA:TPU runs those element by element, seconds a call at full size.
+    And no scatter at all: a uint8 scatter of the right walks wrote wrong
+    bases on a TPU v5e, which no CPU run shows."""
+    C, Lmax, E = 256, 4096, 64
+    contigs = ContigSet(bases=jnp.zeros((C, Lmax), jnp.uint8),
+                        lengths=jnp.zeros((C,), jnp.int32),
+                        depths=jnp.zeros((C,), jnp.float32))
+    walk = local_assembly.WalkResult(
+        ext_bases=jnp.zeros((2 * C, E), jnp.uint8),
+        ext_len=jnp.zeros((2 * C,), jnp.int32),
+        status=jnp.zeros((2 * C,), jnp.int32))
+    alive = jnp.ones((C,), bool)
+    hlo = local_assembly.apply_extensions.lower(
+        contigs, alive, walk).compile().as_text()
+    counts = _index_counts(hlo)
+    assert all(n < C * Lmax for _, n in counts), counts
+    assert all(op != "scatter" for op, _ in counts), counts
+    # the reading finds a per-position gather where there is one
+    per_position = jax.jit(
+        lambda b, s: jnp.take_along_axis(
+            b, jnp.clip(jnp.arange(Lmax)[None, :] - s[:, None], 0), axis=1))
+    hlo = per_position.lower(contigs.bases, contigs.lengths).compile().as_text()
+    assert ("gather", C * Lmax) in _index_counts(hlo)
