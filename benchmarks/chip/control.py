@@ -1,9 +1,10 @@
 """Readings of the checks' numbers: sound runs, the control, planted faults.
 
-    python3 benchmarks/chip/control.py --workload phage_cami.s2500 \
+    python3 benchmarks/chip/control.py --workload phage_cami.s3000 \
         --seconds 45 --runs sound:101 control:201 drop_contig:301
 
-Runs everything in one process on the chip, so the programs compile once:
+Runs everything in one process on the chip, so the programs compile once
+(a planted fault clears JAX's caches, and its run compiles them anew):
 each `<plant>:<seed>` is one short run of the cell (a window of
 `--seconds`, the reference check) through `run.run_cell`, without the
 warm-up assembly.  `sound` runs the program as it is, `control` under the

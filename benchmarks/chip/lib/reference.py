@@ -27,24 +27,46 @@ COMPLEMENT = np.array([3, 2, 1, 0, 4], dtype=np.uint8)
 
 
 def canonical_kmers(rows: np.ndarray, lengths: np.ndarray, k: int):
-    """Canonical codes of every k-window inside a row's length with no N."""
+    """Canonical codes of every k-window inside a row's length with no N:
+    one row of ceil(k / 32) `uint64` words a window.
+
+    A k-mer is packed two bits a base, its first base highest, into words
+    of 32 bases each, the last word holding the rest: comparing two rows
+    word by word compares their bases in order.  The canonical code is the
+    lesser row of the k-mer's and its reverse complement's."""
     rows = np.asarray(rows)
     R, L = rows.shape
     n = L - k + 1
+    words = -(-k // 32)
     if R == 0 or n <= 0:
-        return np.zeros((0,), np.uint64)
+        return np.zeros((0, words), np.uint64)
     ok = (rows < 4) & (np.arange(L)[None, :] < np.asarray(lengths)[:, None])
     bad = np.concatenate([np.zeros((R, 1), np.int64),
                           np.cumsum(~ok, axis=1)], axis=1)
     window_ok = (bad[:, k:] - bad[:, :n]) == 0
     b = (rows & 3).astype(np.uint64)
-    fwd = np.zeros((R, n), np.uint64)
-    rev = np.zeros((R, n), np.uint64)
+    fwd = np.zeros((words, R, n), np.uint64)
+    rev = np.zeros((words, R, n), np.uint64)
     for j in range(k):
         bj = b[:, j:j + n]
-        fwd = (fwd << np.uint64(2)) | bj
-        rev |= (np.uint64(3) - bj) << np.uint64(2 * j)
-    return np.minimum(fwd, rev)[window_ok]
+        fwd[j // 32] = (fwd[j // 32] << np.uint64(2)) | bj
+        # base j's complement is base k-1-j of the reverse complement
+        w, q = divmod(k - 1 - j, 32)
+        width = min(32, k - 32 * w)
+        rev[w] |= (np.uint64(3) - bj) << np.uint64(2 * (width - 1 - q))
+    fwd = np.moveaxis(fwd, 0, -1)[window_ok]
+    rev = np.moveaxis(rev, 0, -1)[window_ok]
+    first = (fwd != rev).argmax(axis=1)
+    at = np.arange(fwd.shape[0])
+    return np.where((rev[at, first] < fwd[at, first])[:, None], rev, fwd)
+
+
+def kmer_keys(rows: np.ndarray, lengths: np.ndarray, k: int) -> np.ndarray:
+    """One key per canonical k-mer, for `unique`, `union1d` and `isin`: its
+    row of words as one opaque value, so that whole rows are compared."""
+    codes = np.ascontiguousarray(canonical_kmers(rows, lengths, k))
+    return codes.view(np.dtype((np.void, codes.itemsize * codes.shape[1]))
+                      ).ravel()
 
 
 def live_rows(contigs: dict):
@@ -71,19 +93,19 @@ def kmer_counts(asm: dict, reads: dict, g: dict):
     for rnd in asm["rounds"]:
         k = rnd["k"]
         codes, counts = np.unique(
-            canonical_kmers(reads["bases"], reads["lengths"], k),
+            kmer_keys(reads["bases"], reads["lengths"], k),
             return_counts=True)
         solid = codes[counts >= g["min_count"]]
         if prev is not None:
             solid = np.union1d(
-                solid, np.unique(canonical_kmers(*live_rows(prev), k)))
+                solid, np.unique(kmer_keys(*live_rows(prev), k)))
         count_diff += abs(int(rnd["n_kmers"]) - int(solid.size))
         rows, lengths = live_rows(rnd)
         e = g["max_ext"]
         inner = np.full_like(rows, 4)
         for i, n in enumerate(lengths):
             inner[i, e:n - e] = rows[i, e:n - e]
-        unsolid += int((~np.isin(canonical_kmers(inner, lengths, k),
+        unsolid += int((~np.isin(kmer_keys(inner, lengths, k),
                                  solid)).sum())
         prev = rnd
     return count_diff, unsolid

@@ -1,6 +1,6 @@
 """Run one cell of the on-chip benchmark and print its result line.
 
-    python3 benchmarks/chip/run.py --workload phage_cami.s2500 --seed 7 \
+    python3 benchmarks/chip/run.py --workload phage_cami.s3000 --seed 7 \
         --seconds 51 --trace 0
 
 The cell, its configuration, its traffic and its per-layer metrics are
@@ -9,9 +9,14 @@ found by name: the workload in `BENCHMARK.json` names a configuration
 each per-layer metric is `layer_metrics/<name>.json`, read by the reader it
 names (`readers/<reader>.py`).
 
-The timed unit is one whole assembly, `Assembler(plan, Local()).assemble`,
-of a fresh MGSim sample drawn from `--seed`, until its rendered scaffolds
-are on the host.  Set-up generates the first sample, then runs one warm-up
+The configuration states its deployment in `assembly.context`: `"Local"`,
+on one chip, or `"Mesh"`, a `Mesh(num_shards=chips)` over the cell's chips,
+planned for that many shards.  A context the harness does not know, or
+`Local` on more than one chip, is refused before set-up.
+
+The timed unit is one whole assembly, `Assembler(plan, ctx).assemble`, of a
+fresh MGSim sample drawn from `--seed`, until its rendered scaffolds are on
+the host.  Set-up generates the first sample, then runs one warm-up
 assembly of the cell's shapes with JAX's compilation cache at a fixed path
 in the checkout.  The window starts assemblies back to back while less
 than `--seconds` of timed assembly have passed, and ends when the last one
@@ -19,9 +24,10 @@ finishes.  What only the check needs is done between timed stretches, with
 the clock stopped: drawing and uploading the next sample, and copying each
 round's contigs and the assembly's other outputs to the host.  Every
 assembly of the window is then checked against the plain reference
-(`lib/reference.py`).  With `--trace 1` the window runs under the JAX
-profiler, and the per-layer metrics come from its trace of the timed
-stretches.
+(`lib/reference.py`).  `peak_hbm_bytes` is the largest `peak_bytes_in_use`
+over the devices the context uses.  With `--trace 1` the window runs under
+the JAX profiler, and the per-layer metrics come from its trace of the
+timed stretches.
 
 Without a TPU, with fewer chips than the cell asks for, or on a device
 missing from `peaks.json`, the run exits non-zero and prints no result.
@@ -89,6 +95,30 @@ def load_module(kind: str, name: str):
     return mod
 
 
+CONTEXTS = ("Local", "Mesh")
+
+
+def deployment(cell: dict) -> str:
+    """The configuration's context, run over the cell's chips.  A context
+    the harness does not know, or `Local` on more than one chip, is
+    refused."""
+    context = cell["config"]["assembly"].get("context")
+    if context not in CONTEXTS:
+        raise ValueError(f"assembly.context {context!r} is none of "
+                         f"{list(CONTEXTS)}")
+    if context == "Local" and cell["chips"] != 1:
+        raise ValueError(f"Local runs on one chip, not {cell['chips']}")
+    return context
+
+
+def peak_bytes(devices):
+    """The largest `peak_bytes_in_use` over `devices`: the fullest chip the
+    context uses.  None where the backend reports none."""
+    in_use = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+              for d in devices]
+    return max((b for b in in_use if b is not None), default=None)
+
+
 def device_peaks(kind: str) -> dict:
     """The published peaks of a device, from `peaks.json`; a device that is
     not in the table is an error, not a default."""
@@ -138,8 +168,9 @@ def _frame_contigs(gen):
     raise RuntimeError("no round contigs in the assembly generator's frames")
 
 
-def assemble(plan, reads, label: str, spans: dict):
-    """One assembly: its timed seconds, and what the check reads, on the host.
+def assemble(plan, ctx, reads, label: str, spans: dict):
+    """One assembly in a fresh binding of `ctx` (`spawn`): its timed
+    seconds, and what the check reads, on the host.
 
     The clock runs from the assembly's start until its rendered scaffolds
     are on the host, and stops while the check's copies are made.  Each
@@ -151,9 +182,9 @@ def assemble(plan, reads, label: str, spans: dict):
     then, with the clock stopped (the final round's are in the result)."""
     import jax
 
-    from repro.api import Assembler, Local
+    from repro.api import Assembler
 
-    gen = Assembler(plan, Local()).assemble_iter(reads)
+    gen = Assembler(plan, ctx.spawn()).assemble_iter(reads)
     rounds, step, timed = [], 0, 0.0
     t = time.perf_counter()
     while True:
@@ -186,6 +217,9 @@ def assemble(plan, reads, label: str, spans: dict):
             "scaffolds": out["scaffolds"]._asdict(),
         })
     host["seqs"] = seqs
+    # a Mesh pads the reads to an even split over its shards, at the end
+    R = reads.bases.shape[0]
+    host["alignments"] = {k: v[:R] for k, v in host["alignments"].items()}
     rounds.append(_trim_contigs({"k": plan.ks()[-1],
                                  "bases": host.pop("bases"),
                                  "lengths": host.pop("lengths"),
@@ -251,9 +285,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     """Set-up, window, reference check and metrics of one run.  Without
     `warmup` (readings of the checks in a process that has run the cell
     before) set-up skips the warm-up assembly."""
+    context, shards = deployment(cell), cell["chips"]
     import jax
 
-    from repro.api import AssemblyPlan
+    from repro.api import AssemblyPlan, Local, Mesh
 
     cfg, pairs = cell["config"], cell["traffic"]["pairs_per_sample"]
     g = cfg["guarantees"]
@@ -268,15 +303,18 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     reads = upload(first)
     asm = cfg["assembly"]
     plan = AssemblyPlan.from_dataset(reads, tuple(asm["k_range"]),
+                                     num_shards=shards,
                                      unique_rate=asm["unique_rate"])
     check_plan(plan, g)
+    ctx = Local() if context == "Local" else Mesh(num_shards=shards)
     if warmup:
-        assemble(plan, reads, "warmup", {})
+        assemble(plan, ctx, reads, "warmup", {})
     del reads
     setup_s = time.perf_counter() - T_START
     before = counter.snapshot()
     print(f"setup: {setup_s:.3f} s; compile {counter.compile_s:.3f} s, "
-          f"{before}; plan.bytes() {plan.bytes()}", flush=True)
+          f"{before}; plan.bytes() {plan.bytes()}; {context} over "
+          f"{shards} of {len(jax.devices())} devices", flush=True)
 
     if trace:
         # host annotations only: the Python tracer would record every call
@@ -291,7 +329,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         i = len(results) + 1
         samples.append(draw(i))
         reads = upload(samples[-1])
-        timed, host = assemble(plan, reads, f"a{i}", spans)
+        timed, host = assemble(plan, ctx, reads, f"a{i}", spans)
         del reads
         window_s += timed
         results.append(host)
@@ -302,8 +340,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     print(f"window: {len(results)} assemblies in {window_s:.6f} s timed "
           f"({wall_s:.6f} s with the check's copies and the samples' "
           f"upload); inside the window {during}", flush=True)
-    stats = jax.devices()[0].memory_stats() or {}
-    peak_bytes = stats.get("peak_bytes_in_use")
+    peak = peak_bytes(jax.devices()[:shards])
 
     t_check = time.perf_counter()
     checks, per_asm = judge(results, samples, g)
@@ -316,7 +353,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         "attempted": len(results),
         "failed": sum(1 for r in per_asm if not r["ok"]),
         "metrics": {},
-        "device": dict(device, memory_peak_bytes=peak_bytes),
+        "device": dict(device, memory_peak_bytes=peak),
     }
     if trace:
         from lib import tracing
@@ -339,7 +376,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     else:
         e2e = {"read_bases_per_s": bases / window_s,
                "genome_fraction_10x": sum(deep) / len(deep) if deep else 0.0,
-               "peak_hbm_bytes": peak_bytes,
+               "peak_hbm_bytes": peak,
                "setup_s": setup_s}
         for m in cell["end_to_end"]:
             if e2e.get(m["name"]) is not None:
@@ -410,6 +447,11 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     cell = load_cell(args.workload)
+    try:
+        deployment(cell)
+    except ValueError as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 1
     jax = use_compile_cache()
     devs = jax.devices()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,

@@ -20,7 +20,7 @@ def tiny_cell():
     2 genomes of 3-4 kbp (~17x), every guarantee and limit as stated."""
     import run
 
-    cell = run.load_cell("phage_cami.s2500", ROOT)
+    cell = run.load_cell("phage_cami.s3000", ROOT)
     cell = copy.deepcopy(cell)
     cell["config"]["community"].update(genome_len=[3000, 4000],
                                        pairs_per_genome=200)

@@ -122,7 +122,7 @@ def test_walk_table_work_depends_only_on_shapes():
     from repro.core.types import ReadSet
 
     work = run.load_module("work", "walk_tables")
-    cfg = run.load_cell("phage_cami.s2500", ROOT)["config"]
+    cfg = run.load_cell("phage_cami.s3000", ROOT)["config"]
     got = []
     for seed in (1, 2**33 + 7):
         s = community.sample(seed, 0, pairs=300, community=cfg["community"],
@@ -143,7 +143,7 @@ def test_no_tpu_exits_nonzero_without_a_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         "phage_cami.s2500", "--seed", "3", "--seconds", "1", "--trace", "0"],
+         "phage_cami.s3000", "--seed", "3", "--seconds", "1", "--trace", "0"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
     assert p.returncode != 0
     assert '"correct"' not in p.stdout
@@ -155,7 +155,7 @@ def test_device_missing_from_peak_table_exits_nonzero(monkeypatch, capsys):
 
     fake = NS(platform="tpu", device_kind="TPU v0 imaginary")
     monkeypatch.setattr(jax, "devices", lambda *a: [fake])
-    rc = run.main(["--workload", "phage_cami.s2500", "--seed", "3",
+    rc = run.main(["--workload", "phage_cami.s3000", "--seed", "3",
                    "--seconds", "1"])
     out = capsys.readouterr()
     assert rc != 0 and '"correct"' not in out.out
@@ -168,7 +168,7 @@ def test_device_missing_from_peak_table_exits_nonzero(monkeypatch, capsys):
 def test_samples_repeat_from_the_seed():
     from lib import community
 
-    cfg = run.load_cell("phage_cami.s2500", ROOT)["config"]
+    cfg = run.load_cell("phage_cami.s3000", ROOT)["config"]
     kw = dict(pairs=2500, community=dict(cfg["community"],
                                          genome_len=[5000, 10000],
                                          abundance_sigma=1.0,
